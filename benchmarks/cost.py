@@ -37,6 +37,9 @@ from .common import emit
 
 _CHILD = r"""
 import os
+# a CPU rehearsal on 8 virtual devices: never the accelerator the
+# parent process may hold
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys, json, tempfile, time
 sys.path.insert(0, r"%(src)s")

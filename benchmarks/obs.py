@@ -155,6 +155,9 @@ def run_local(n_orders: int = 400, smoke: bool = True) -> dict:
 
 _DIST_CHILD = r"""
 import os, sys, json
+# a CPU rehearsal on 8 virtual devices: never the accelerator the
+# parent process may hold
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 sys.path.insert(0, %(src)r)
 import jax, numpy as np

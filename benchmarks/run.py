@@ -83,6 +83,9 @@ def main() -> None:
         return
     # fail fast on an unwritable destination, not after the full run
     os.makedirs(args.out_dir, exist_ok=True)
+    from repro import compile_cache
+    compile_cache.enable(os.path.join(os.path.dirname(
+        os.path.abspath(__file__)), ".."))
 
     print("name,us_per_call,derived")
     sections = []

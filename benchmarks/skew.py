@@ -46,6 +46,9 @@ from .common import emit
 
 _CHILD = r"""
 import os
+# a CPU rehearsal on 8 virtual devices: never the accelerator the
+# parent process may hold
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys, json, time
 sys.path.insert(0, r"%(src)s")
@@ -114,6 +117,9 @@ print("JSON" + json.dumps(out))
 
 _AUTO_CHILD = r"""
 import os
+# a CPU rehearsal on 8 virtual devices: never the accelerator the
+# parent process may hold
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys, json, tempfile, time
 sys.path.insert(0, r"%(src)s")

@@ -419,6 +419,18 @@ def compile_program_distributed(
     moves only on an actual retrace)."""
     _compile_fault("dist")
     from repro.exec import dist as D
+    fn, defaults = dist_program_fn(cp, use_kernel, outputs, params)
+    return D.compile_distributed(fn, env, mesh, use_kernel=use_kernel,
+                                 params=defaults, **dist_kwargs)
+
+
+def dist_program_fn(cp: CompiledProgram, use_kernel: bool = False,
+                    outputs: Optional[tuple] = None,
+                    params: Optional[Dict[str, object]] = None):
+    """``(fn, params)``: the program schedule as the
+    ``fn(env_local, ctx, params_local)`` that ``exec.dist`` runs per
+    partition, and its parameter bindings (defaults overridden by
+    ``params``)."""
     outs = tuple(outputs) if outputs is not None \
         else (tuple(cp.outputs) or tuple(n for n, _ in cp.plans))
     defaults = collect_params(cp.graph) if cp.graph is not None else {}
@@ -443,8 +455,7 @@ def compile_program_distributed(
                 local[name] = eval_plan(plan, local, s)
             return {o: local[o] for o in outs}
 
-    return D.compile_distributed(fn, env, mesh, use_kernel=use_kernel,
-                                 params=defaults, **dist_kwargs)
+    return fn, defaults
 
 
 # ---------------------------------------------------------------------------

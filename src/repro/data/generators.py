@@ -9,6 +9,7 @@
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -31,14 +32,29 @@ TPCH_TYPES = {"Part": PART_T, "Lineitem": LINEITEM_T, "Orders": ORDERS_T,
               "Region": REGION_T}
 
 
-def zipf_choice(rng, n: int, skew: float, size: int) -> np.ndarray:
-    """Zipf-ish keys in [1, n]; skew=0 -> uniform (paper's generator)."""
-    if skew <= 0:
-        return rng.randint(1, n + 1, size=size)
+@functools.lru_cache(maxsize=16)
+def _zipf_cdf(n: int, skew: float) -> np.ndarray:
+    """The CDF ``RandomState.choice(p=ranks**-skew)`` searches, built
+    with the same operations in the same order."""
     ranks = np.arange(1, n + 1, dtype=np.float64)
     probs = ranks ** (-skew)
     probs /= probs.sum()
-    return rng.choice(np.arange(1, n + 1), size=size, p=probs)
+    cdf = probs.cumsum()
+    cdf /= cdf[-1]
+    return cdf
+
+
+def zipf_choice(rng, n: int, skew: float, size: int) -> np.ndarray:
+    """Zipf-ish keys in [1, n]; skew=0 -> uniform (paper's generator).
+
+    Draw for draw what ``rng.choice(np.arange(1, n + 1), size, p=...)``
+    returns (one ``random_sample`` per key, searched in the CDF), with
+    the CDF built once per ``(n, skew)``: O(log n) per key instead of
+    O(n)."""
+    if skew <= 0:
+        return rng.randint(1, n + 1, size=size)
+    u = rng.random_sample(size)
+    return _zipf_cdf(int(n), float(skew)).searchsorted(u, side="right") + 1
 
 
 def gen_tpch(scale: int = 100, skew: float = 0.0, seed: int = 0

@@ -130,7 +130,10 @@ class DistContext:
     def finalize_metrics(self) -> Dict[str, jnp.ndarray]:
         out = {k: jax.lax.psum(v, self.axis)
                for k, v in self.metrics.items()}
-        out.update({k: jax.lax.pmax(v, self.axis)
+        # the TPU all-reduces 64-bit integers by sum only; max metrics
+        # are per-partition row counts, which fit in 32 bits
+        out.update({k: jax.lax.pmax(v.astype(jnp.int32),
+                                    self.axis).astype(jnp.int64)
                     for k, v in self.max_metrics.items()})
         return out
 
@@ -730,9 +733,15 @@ class DistContext:
 # ---------------------------------------------------------------------------
 
 def device_mesh_1d(n: int, axis: str = "data") -> Mesh:
-    devs = jax.devices()[:n]
+    """1-D mesh over the first ``n`` devices; raises when there are
+    fewer (a smaller mesh would change every partitioned plan)."""
     import numpy as np
-    return Mesh(np.array(devs), (axis,))
+    devs = jax.devices()
+    if len(devs) < n:
+        raise ValueError(
+            f"a {n}-partition mesh needs {n} devices; found {len(devs)} "
+            f"{devs[0].platform} device(s)")
+    return Mesh(np.array(devs[:n]), (axis,))
 
 
 def _bag_specs(tree, axis: str):
@@ -794,6 +803,44 @@ class DistRunner:
             {k: int(v) for k, v in metrics.items()}, self.stats)
 
 
+def shard_program(fn: Callable, mesh: Mesh, axis: str = "data",
+                  has_params: bool = False, jit: bool = True,
+                  size_plan: Optional[Sequence[int]] = None,
+                  cap_factor: float = 2.0, threshold: float = 0.025,
+                  skew_default: bool = False,
+                  shuffle_mode: str = "packed",
+                  use_kernel: bool = False) -> Callable:
+    """``fn(env_local, ctx[, params_local])`` as one SPMD program over
+    ``mesh[axis]``, returning ``(outputs, metrics)``; nothing runs.
+    ``compile_distributed`` calls it once per sizing attempt, and a
+    caller may ``.lower(...)`` it for devices that are only described.
+    Bags are row-sharded, params (when present) and metrics
+    replicated."""
+    n = mesh.shape[axis]
+
+    def make_ctx():
+        return DistContext(axis, n, cap_factor=cap_factor, sample=256,
+                           threshold=threshold, skew_default=skew_default,
+                           packed=(shuffle_mode == "packed"),
+                           size_plan=size_plan, use_kernel=use_kernel)
+
+    if has_params:
+        def inner(env_local, params_local):
+            ctx = make_ctx()
+            out = fn(env_local, ctx, params_local)
+            return out, ctx.finalize_metrics()
+    else:
+        def inner(env_local):
+            ctx = make_ctx()
+            out = fn(env_local, ctx)
+            return out, ctx.finalize_metrics()
+
+    in_specs = (P(axis), P()) if has_params else (P(axis),)
+    sm = jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
+                       out_specs=(P(axis), P()), check_vma=False)
+    return jax.jit(sm) if jit else sm
+
+
 def compile_distributed(
         fn: Callable[[Dict[str, FlatBag], DistContext], dict],
         env: Dict[str, FlatBag], mesh: Mesh,
@@ -841,42 +888,19 @@ def compile_distributed(
             f"bag {k} capacity {b.capacity} not divisible by {n} partitions")
     assert shuffle_mode in ("packed", "legacy"), shuffle_mode
 
-    from jax.experimental.shard_map import shard_map
-
-    # pytree-prefix specs: bags row-sharded; params (when present)
-    # replicated; outputs sharded, metrics replicated
     has_params = params is not None
-    in_specs = (P(axis), P()) if has_params else (P(axis),)
-    out_specs = (P(axis), P())
     pvals = {k: jnp.asarray(v) for k, v in (params or {}).items()}
 
     size_plan: Optional[Tuple[int, ...]] = None
     attempt = 0
     while True:
         reset_shuffle_stats()
-
-        def make_ctx(_plan):
-            return DistContext(axis, n, cap_factor=cap_factor,
-                               sample=256, threshold=threshold,
-                               skew_default=skew_default,
-                               packed=(shuffle_mode == "packed"),
-                               size_plan=_plan, use_kernel=use_kernel)
-
-        if has_params:
-            def inner(env_local, params_local, _plan=size_plan):
-                ctx = make_ctx(_plan)
-                out = fn(env_local, ctx, params_local)
-                return out, ctx.finalize_metrics()
-        else:
-            def inner(env_local, _plan=size_plan):
-                ctx = make_ctx(_plan)
-                out = fn(env_local, ctx)
-                return out, ctx.finalize_metrics()
-
-        sm = shard_map(inner, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
-        if jit:
-            sm = jax.jit(sm)
+        sm = shard_program(fn, mesh, axis=axis, has_params=has_params,
+                           jit=jit, size_plan=size_plan,
+                           cap_factor=cap_factor, threshold=threshold,
+                           skew_default=skew_default,
+                           shuffle_mode=shuffle_mode,
+                           use_kernel=use_kernel)
         out, metrics = sm(env, pvals) if has_params else sm(env)
         host = dict(SHUFFLE_STATS)
         runner = DistRunner(sm, host, pvals if has_params else None)

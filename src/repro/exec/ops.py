@@ -330,12 +330,38 @@ def _build_side(right: FlatBag, right_on: Tuple[str, ...]
     return order_r, srk
 
 
+def _f64_pair_bits(a: jnp.ndarray) -> jnp.ndarray:
+    """float64 -> int64 on the TPU, which refuses a 64-bit bitcast and
+    holds a float64 as an unevaluated pair of float32s (hi + lo;
+    DESIGN.md "float64 on the TPU"). The pair's two 32-bit patterns
+    fill the lane, so the round trip is exact for every value the
+    device can hold, and equal values give equal lanes."""
+    hi = a.astype(jnp.float32)
+    # inf - inf would make the low word NaN and the value NaN
+    lo = jnp.where(jnp.isinf(hi), 0.0,
+                   a - hi.astype(jnp.float64)).astype(jnp.float32)
+    hb = jax.lax.bitcast_convert_type(hi, jnp.int32).astype(jnp.int64)
+    lb = jax.lax.bitcast_convert_type(lo, jnp.uint32).astype(jnp.int64)
+    return (hb << 32) | lb
+
+
+def _f64_from_pair_bits(a: jnp.ndarray) -> jnp.ndarray:
+    hi = jax.lax.bitcast_convert_type((a >> 32).astype(jnp.int32),
+                                      jnp.float32)
+    lo = jax.lax.bitcast_convert_type(a.astype(jnp.uint32), jnp.float32)
+    return hi.astype(jnp.float64) + lo.astype(jnp.float64)
+
+
 def _to_i64_bits(a: jnp.ndarray) -> jnp.ndarray:
-    """Lossless int64 view of a column (for kernel gathers)."""
+    """Lossless int64 view of a column (exchange lanes, sort keys,
+    kernel gathers). A float64 is its IEEE bit pattern, except when the
+    program is lowered for a TPU (``_f64_pair_bits``)."""
     if a.dtype == jnp.int64:
         return a
     if a.dtype == jnp.float64:
-        return jax.lax.bitcast_convert_type(a, jnp.int64)
+        return jax.lax.platform_dependent(
+            a, tpu=_f64_pair_bits,
+            default=lambda x: jax.lax.bitcast_convert_type(x, jnp.int64))
     if a.dtype == jnp.float32:
         return jax.lax.bitcast_convert_type(a, jnp.int32).astype(jnp.int64)
     return a.astype(jnp.int64)
@@ -345,7 +371,9 @@ def _from_i64_bits(a: jnp.ndarray, dtype) -> jnp.ndarray:
     if dtype == jnp.int64:
         return a
     if dtype == jnp.float64:
-        return jax.lax.bitcast_convert_type(a, jnp.float64)
+        return jax.lax.platform_dependent(
+            a, tpu=_f64_from_pair_bits,
+            default=lambda x: jax.lax.bitcast_convert_type(x, jnp.float64))
     if dtype == jnp.float32:
         return jax.lax.bitcast_convert_type(a.astype(jnp.int32), jnp.float32)
     return a.astype(dtype)

@@ -37,29 +37,37 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import words as W
+
 DEF_BLOCK_N = 256     # output rows per grid step
 DEF_BLOCK_R = 256     # runs / dictionary entries per grid step
+DEF_BLOCK_W = 512     # packed words per grid step
 
 
 # ---------------------------------------------------------------------------
 # rle_expand
 # ---------------------------------------------------------------------------
 
-def _rle_kernel(values_ref, starts_ref, ends_ref, out_ref, *, block_n):
+def _rle_kernel(vh_ref, vl_ref, s_ref, e_ref, oh_ref, ol_ref, *,
+                block_n):
     nb = pl.program_id(0)
     rb = pl.program_id(1)
 
     @pl.when(rb == 0)
     def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        oh_ref[...] = jnp.zeros_like(oh_ref)
+        ol_ref[...] = jnp.zeros_like(ol_ref)
 
-    i = (nb * block_n
-         + jax.lax.broadcasted_iota(jnp.int64, (block_n, 1), 0))
-    s = starts_ref[...][None, :]
-    e = ends_ref[...][None, :]
-    hit = (s <= i) & (i < e)          # exactly one run covers each row
-    out_ref[...] += jnp.sum(
-        jnp.where(hit, values_ref[...][None, :], 0), axis=1)
+    i = nb * block_n + jax.lax.broadcasted_iota(
+        jnp.int32, (block_n, 1), 0)
+    hit = (s_ref[0, :][None, :] <= i) & (i < e_ref[0, :][None, :])
+    # exactly one run covers each row: the masked sum IS the gather
+    oh_ref[0, :] += jnp.sum(jnp.where(hit, vh_ref[0, :][None, :],
+                                      jnp.int32(0)),
+                            axis=1, dtype=jnp.int32)
+    ol_ref[0, :] += jnp.sum(jnp.where(hit, vl_ref[0, :][None, :],
+                                      jnp.int32(0)),
+                            axis=1, dtype=jnp.int32)
 
 
 def rle_expand_pallas(values: jnp.ndarray, starts: jnp.ndarray,
@@ -68,32 +76,27 @@ def rle_expand_pallas(values: jnp.ndarray, starts: jnp.ndarray,
                       block_r: int = DEF_BLOCK_R,
                       interpret: bool = True) -> jnp.ndarray:
     """out[i] = values[j] for the run j with starts[j] <= i < ends[j].
-    values/starts/ends (r,) int64, runs sorted and tiling [0, n)."""
+    values/starts/ends (r,) int64, runs sorted and tiling [0, n) (row
+    positions fit in int32; values cross as word rows)."""
     r = values.shape[0]
-    block_n = max(1, min(block_n, n))
-    block_r = max(1, min(block_r, max(r, 1)))
-    n_pad = (-n) % block_n if n else block_n
-    r_pad = (-r) % block_r if r else block_r
-    if r_pad:
-        # empty interval [0, 0): padding runs never cover a row
-        values = jnp.pad(values, (0, r_pad))
-        starts = jnp.pad(starts, (0, r_pad))
-        ends = jnp.pad(ends, (0, r_pad))
-    grid = ((n + n_pad) // block_n, (r + r_pad) // block_r)
-    out = pl.pallas_call(
-        functools.partial(_rle_kernel, block_n=block_n),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_r,), lambda nb, rb: (rb,)),
-            pl.BlockSpec((block_r,), lambda nb, rb: (rb,)),
-            pl.BlockSpec((block_r,), lambda nb, rb: (rb,)),
-        ],
-        out_specs=pl.BlockSpec((block_n,), lambda nb, rb: (nb,)),
-        out_shape=jax.ShapeDtypeStruct((n + n_pad,), jnp.int64),
+    bn = W.lanes_for(n, block_n)
+    br = W.lanes_for(r, block_r)
+    # empty interval [0, 0): padding runs never cover a row
+    vh, vl = (W.row(w, br) for w in W.split64(values))
+    st = W.row(starts.astype(jnp.int32), br)
+    en = W.row(ends.astype(jnp.int32), br)
+    width = W.row(jnp.zeros((max(n, 1),), jnp.int32), bn).shape[1]
+    r_spec = pl.BlockSpec((1, br), lambda nb, rb: (jnp.int32(0), rb))
+    o_spec = pl.BlockSpec((1, bn), lambda nb, rb: (jnp.int32(0), nb))
+    oh, ol = pl.pallas_call(
+        functools.partial(_rle_kernel, block_n=bn),
+        grid=(width // bn, vh.shape[1] // br),
+        in_specs=[r_spec] * 4,
+        out_specs=[o_spec, o_spec],
+        out_shape=[jax.ShapeDtypeStruct((1, width), jnp.int32)] * 2,
         interpret=interpret,
-    )(values.astype(jnp.int64), starts.astype(jnp.int64),
-      ends.astype(jnp.int64))
-    return out[:n]
+    )(vh, vl, st, en)
+    return W.join64(oh[0, :n], ol[0, :n])
 
 
 # ---------------------------------------------------------------------------
@@ -146,54 +149,55 @@ def delta_unpack_pallas(z: jnp.ndarray, first: jnp.ndarray,
 # bitunpack
 # ---------------------------------------------------------------------------
 
-def _bitunpack_kernel(words_ref, out_ref, *, k, vpw, lo):
-    w = words_ref[...]
-    rep = jnp.repeat(w, vpw)
-    m = rep.shape[0]
-    pos = (jax.lax.broadcasted_iota(jnp.uint32, (m,), 0)
-           % jnp.uint32(vpw))
-    vals = (rep >> (pos * jnp.uint32(k))) & jnp.uint32((1 << k) - 1)
-    out_ref[...] = vals.astype(jnp.int64) + jnp.int64(lo)
+def _bitunpack_kernel(words_ref, out_ref, *, k, vpw):
+    w = words_ref[0, :][None, :]                        # (1, block_w)
+    shift = jax.lax.broadcasted_iota(
+        jnp.uint32, (vpw, w.shape[1]), 0) * jnp.uint32(k)
+    out_ref[...] = (w >> shift) & jnp.uint32((1 << k) - 1)
 
 
 def bitunpack_pallas(words: jnp.ndarray, k: int, vpw: int, n: int,
-                     lo: int, block_w: int = DEF_BLOCK_N,
+                     lo: int, block_w: int = DEF_BLOCK_W,
                      interpret: bool = True) -> jnp.ndarray:
     """Frame-of-reference unpack: word i holds values [i*vpw, i*vpw+vpw)
-    at k bits each; out = unpacked + lo as int64, trimmed to n rows."""
+    at k bits each; out = unpacked + lo as int64, trimmed to n rows.
+    The kernel unpacks value j of every word into row j of a
+    ``(vpw, words)`` uint32 block; the 64-bit ``+ lo`` runs after it."""
     nw = words.shape[0]
-    block_w = max(1, min(block_w, max(nw, 1)))
-    w_pad = (-nw) % block_w if nw else block_w
-    if w_pad:
-        words = jnp.pad(words, (0, w_pad))
-    grid = ((nw + w_pad) // block_w,)
+    bw = W.lanes_for(nw, block_w)
+    wr = W.row(words.astype(jnp.uint32), bw)
     out = pl.pallas_call(
-        functools.partial(_bitunpack_kernel, k=k, vpw=vpw, lo=lo),
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_w,), lambda b: (b,))],
-        out_specs=pl.BlockSpec((block_w * vpw,), lambda b: (b,)),
-        out_shape=jax.ShapeDtypeStruct(((nw + w_pad) * vpw,), jnp.int64),
+        functools.partial(_bitunpack_kernel, k=k, vpw=vpw),
+        grid=(wr.shape[1] // bw,),
+        in_specs=[pl.BlockSpec((1, bw), lambda b: (jnp.int32(0), b))],
+        out_specs=pl.BlockSpec((vpw, bw), lambda b: (jnp.int32(0), b)),
+        out_shape=jax.ShapeDtypeStruct((vpw, wr.shape[1]), jnp.uint32),
         interpret=interpret,
-    )(words.astype(jnp.uint32))
-    return out[:n]
+    )(wr)
+    return out.T.reshape(-1)[:n].astype(jnp.int64) + jnp.int64(lo)
 
 
 # ---------------------------------------------------------------------------
 # dict_gather
 # ---------------------------------------------------------------------------
 
-def _dict_kernel(codes_ref, values_ref, out_ref, *, block_v):
+def _dict_kernel(codes_ref, vh_ref, vl_ref, oh_ref, ol_ref, *, block_v):
     vb = pl.program_id(1)
 
     @pl.when(vb == 0)
     def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        oh_ref[...] = jnp.zeros_like(oh_ref)
+        ol_ref[...] = jnp.zeros_like(ol_ref)
 
-    local = codes_ref[...].astype(jnp.int32) - vb * block_v
-    onehot = (local[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (local.shape[0], block_v), 1))
-    out_ref[...] += jnp.sum(
-        jnp.where(onehot, values_ref[...][None, :], 0), axis=1)
+    local = codes_ref[0, :][:, None] - vb * block_v
+    onehot = local == jax.lax.broadcasted_iota(
+        jnp.int32, (local.shape[0], block_v), 1)
+    oh_ref[0, :] += jnp.sum(jnp.where(onehot, vh_ref[0, :][None, :],
+                                      jnp.int32(0)),
+                            axis=1, dtype=jnp.int32)
+    ol_ref[0, :] += jnp.sum(jnp.where(onehot, vl_ref[0, :][None, :],
+                                      jnp.int32(0)),
+                            axis=1, dtype=jnp.int32)
 
 
 def dict_gather_pallas(values: jnp.ndarray, codes: jnp.ndarray,
@@ -201,27 +205,22 @@ def dict_gather_pallas(values: jnp.ndarray, codes: jnp.ndarray,
                        block_v: int = DEF_BLOCK_R,
                        interpret: bool = True) -> jnp.ndarray:
     """out[i] = values[codes[i]] — the dictionary decode as a blocked
-    masked one-hot integer sum (out-of-range codes gather 0)."""
+    masked one-hot integer sum over the values' 32-bit words
+    (out-of-range codes gather 0)."""
     r = values.shape[0]
     n = codes.shape[0]
-    block_n = max(1, min(block_n, max(n, 1)))
-    block_v = max(1, min(block_v, max(r, 1)))
-    n_pad = (-n) % block_n if n else block_n
-    r_pad = (-r) % block_v if r else block_v
-    if n_pad:
-        codes = jnp.pad(codes, (0, n_pad), constant_values=-1)
-    if r_pad:
-        values = jnp.pad(values, (0, r_pad))
-    grid = ((n + n_pad) // block_n, (r + r_pad) // block_v)
-    out = pl.pallas_call(
-        functools.partial(_dict_kernel, block_v=block_v),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n,), lambda nb, vb: (nb,)),
-            pl.BlockSpec((block_v,), lambda nb, vb: (vb,)),
-        ],
-        out_specs=pl.BlockSpec((block_n,), lambda nb, vb: (nb,)),
-        out_shape=jax.ShapeDtypeStruct((n + n_pad,), jnp.int64),
+    bn = W.lanes_for(n, block_n)
+    bv = W.lanes_for(r, block_v)
+    cr = W.row(codes.astype(jnp.int32), bn, -1)
+    vh, vl = (W.row(w, bv) for w in W.split64(values))
+    c_spec = pl.BlockSpec((1, bn), lambda nb, vb: (jnp.int32(0), nb))
+    v_spec = pl.BlockSpec((1, bv), lambda nb, vb: (jnp.int32(0), vb))
+    oh, ol = pl.pallas_call(
+        functools.partial(_dict_kernel, block_v=bv),
+        grid=(cr.shape[1] // bn, vh.shape[1] // bv),
+        in_specs=[c_spec, v_spec, v_spec],
+        out_specs=[c_spec, c_spec],
+        out_shape=[jax.ShapeDtypeStruct(cr.shape, jnp.int32)] * 2,
         interpret=interpret,
-    )(codes.astype(jnp.int32), values.astype(jnp.int64))
-    return out[:n]
+    )(cr, vh, vl)
+    return W.join64(oh[0, :n], ol[0, :n])

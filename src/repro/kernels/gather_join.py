@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import words as W
+
 
 DEF_BLOCK_Q = 256      # probe rows per grid step
 DEF_BLOCK_R = 256      # build rows per grid step (accumulation axis)
@@ -39,23 +41,28 @@ DEF_BLOCK_N = 128      # gather output rows per grid step
 DEF_BLOCK_SRC = 128    # gather source rows per grid step
 
 
-def _merge_kernel(sk_ref, q_ref, out_ref, *, block_q, block_r, n_build):
+def _merge_kernel(sh_ref, sl_ref, qh_ref, ql_ref, lo_ref, hi_ref, *,
+                  block_r, n_build):
     rb = pl.program_id(1)           # build-block index (fastest; accumulates)
 
     @pl.when(rb == 0)
     def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        lo_ref[...] = jnp.zeros_like(lo_ref)
+        hi_ref[...] = jnp.zeros_like(hi_ref)
 
-    q = q_ref[...]                  # (block_q,)
-    sk = sk_ref[...]                # (block_r,)
+    # 64-bit order from 32-bit words: high words signed, low words
+    # biased so that signed order is their unsigned order
+    qh = qh_ref[0, :][:, None]      # (block_q, 1)
+    ql = ql_ref[0, :][:, None]
+    sh = sh_ref[0, :][None, :]      # (1, block_r)
+    sl = sl_ref[0, :][None, :]
     col = rb * block_r + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_r), 1)
+        jnp.int32, (qh.shape[0], block_r), 1)
     inb = col < n_build             # padded build slots count as +inf
-    lt = ((sk[None, :] < q[:, None]) & inb).astype(jnp.int32)
-    le = ((sk[None, :] <= q[:, None]) & inb).astype(jnp.int32)
-    out_ref[...] += jnp.stack(
-        [jnp.sum(lt, axis=1, dtype=jnp.int32),
-         jnp.sum(le, axis=1, dtype=jnp.int32)], axis=1)
+    lt = ((sh < qh) | ((sh == qh) & (sl < ql))) & inb
+    le = (lt | ((sh == qh) & (sl == ql))) & inb
+    lo_ref[0, :] += jnp.sum(lt.astype(jnp.int32), axis=1, dtype=jnp.int32)
+    hi_ref[0, :] += jnp.sum(le.astype(jnp.int32), axis=1, dtype=jnp.int32)
 
 
 def merge_positions_pallas(sorted_keys: jnp.ndarray, queries: jnp.ndarray,
@@ -64,34 +71,30 @@ def merge_positions_pallas(sorted_keys: jnp.ndarray, queries: jnp.ndarray,
                            interpret: bool = True
                            ) -> tuple:
     """(lo, hi) insertion points of ``queries`` into ``sorted_keys`` —
-    bitwise identical to jnp.searchsorted(side=left/right)."""
-    sorted_keys = sorted_keys.astype(jnp.int64)
-    queries = queries.astype(jnp.int64)
+    bitwise identical to jnp.searchsorted(side=left/right). Keys enter
+    as int32 word rows (``kernels.words``)."""
     r = sorted_keys.shape[0]
     n = queries.shape[0]
-    block_q = min(block_q, n)
-    block_r = min(block_r, r)
-    n_pad = (-n) % block_q
-    r_pad = (-r) % block_r
-    if n_pad:
-        queries = jnp.pad(queries, (0, n_pad))
-    if r_pad:
-        sorted_keys = jnp.pad(sorted_keys, (0, r_pad))
+    bq = W.lanes_for(n, block_q)
+    br = W.lanes_for(r, block_r)
 
-    grid = ((n + n_pad) // block_q, (r + r_pad) // block_r)
-    out = pl.pallas_call(
-        functools.partial(_merge_kernel, block_q=block_q, block_r=block_r,
-                          n_build=r),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_r,), lambda qb, rb: (rb,)),
-            pl.BlockSpec((block_q,), lambda qb, rb: (qb,)),
-        ],
-        out_specs=pl.BlockSpec((block_q, 2), lambda qb, rb: (qb, 0)),
-        out_shape=jax.ShapeDtypeStruct((n + n_pad, 2), jnp.int32),
+    def rows(a, width):
+        hi, lo = W.split64(a)
+        return W.row(hi, width), W.row(lo ^ W.LO_BIAS, width)
+
+    sh, sl = rows(sorted_keys, br)
+    qh, ql = rows(queries, bq)
+    q_spec = pl.BlockSpec((1, bq), lambda qb, rb: (jnp.int32(0), qb))
+    r_spec = pl.BlockSpec((1, br), lambda qb, rb: (jnp.int32(0), rb))
+    lo, hi = pl.pallas_call(
+        functools.partial(_merge_kernel, block_r=br, n_build=r),
+        grid=(qh.shape[1] // bq, sh.shape[1] // br),
+        in_specs=[r_spec, r_spec, q_spec, q_spec],
+        out_specs=[q_spec, q_spec],
+        out_shape=[jax.ShapeDtypeStruct(qh.shape, jnp.int32)] * 2,
         interpret=interpret,
-    )(sorted_keys, queries)
-    return out[:n, 0], out[:n, 1]
+    )(sh, sl, qh, ql)
+    return lo[0, :n], hi[0, :n]
 
 
 def _gather_kernel(idx_ref, val_ref, out_ref, *, block_n, block_src):

@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode; on TPU
-set ``repro.kernels.ops.INTERPRET = False`` (the launcher does this when
-it detects TPU devices). Each wrapper falls back to the jnp oracle when
+Each call compiles its kernel with Mosaic when JAX's backend is the TPU
+and runs it in the Pallas interpreter otherwise (``_interpret``): a
+kernel the TPU compiler refuses raises there, it never falls back to
+the interpreter. Each wrapper falls back to the jnp oracle when
 ``USE_REF`` is set — the knob benchmarks use to compare.
 """
 
@@ -24,13 +25,12 @@ from .segment_reduce import segment_reduce_pallas
 from .shuffle_pack import (member_mask_pallas, pack_rows_pallas,
                            replicate_scatter_pallas, unpack_cols_pallas)
 
-INTERPRET = True    # CPU container: interpret mode; launcher flips on TPU
 USE_REF = False
 
 
-def detect_backend():
-    global INTERPRET
-    INTERPRET = jax.default_backend() != "tpu"
+def _interpret() -> bool:
+    """Interpret mode exactly when the backend is not a TPU."""
+    return jax.default_backend() != "tpu"
 
 
 def segment_reduce(values: jnp.ndarray, seg_ids: jnp.ndarray,
@@ -46,7 +46,7 @@ def segment_reduce(values: jnp.ndarray, seg_ids: jnp.ndarray,
     else:
         out = segment_reduce_pallas(values.astype(jnp.float32),
                                     seg_ids, num_segments,
-                                    interpret=INTERPRET)
+                                    interpret=_interpret())
     out = out.astype(dtype)
     return out[:, 0] if squeeze else out
 
@@ -60,7 +60,7 @@ def segment_sum_first(values: jnp.ndarray, keys: jnp.ndarray,
         return ref.segment_sum_first_ref(values, keys, seg_ids,
                                          num_segments)
     return segment_sum_first_pallas(values, keys, seg_ids, num_segments,
-                                    interpret=INTERPRET)
+                                    interpret=_interpret())
 
 
 def merge_positions(sorted_keys: jnp.ndarray, queries: jnp.ndarray) -> tuple:
@@ -69,7 +69,7 @@ def merge_positions(sorted_keys: jnp.ndarray, queries: jnp.ndarray) -> tuple:
     if USE_REF:
         return ref.merge_positions_ref(sorted_keys, queries)
     return merge_positions_pallas(sorted_keys, queries,
-                                  interpret=INTERPRET)
+                                  interpret=_interpret())
 
 
 def gather_rows(values: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
@@ -77,7 +77,7 @@ def gather_rows(values: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     indices gather 0."""
     if USE_REF:
         return ref.gather_rows_ref(values, idx)
-    return gather_rows_pallas(values, idx, interpret=INTERPRET)
+    return gather_rows_pallas(values, idx, interpret=_interpret())
 
 
 def pack_rows(values: jnp.ndarray, idx: jnp.ndarray,
@@ -86,7 +86,7 @@ def pack_rows(values: jnp.ndarray, idx: jnp.ndarray,
     (else 0). values (n, d) int64 bit-view lanes."""
     if USE_REF:
         return ref.pack_rows_ref(values, idx, ok)
-    return pack_rows_pallas(values, idx, ok, interpret=INTERPRET)
+    return pack_rows_pallas(values, idx, ok, interpret=_interpret())
 
 
 def replicate_scatter(values: jnp.ndarray, vidx: jnp.ndarray,
@@ -97,14 +97,14 @@ def replicate_scatter(values: jnp.ndarray, vidx: jnp.ndarray,
     if USE_REF:
         return ref.replicate_scatter_ref(values, vidx, ok, repl)
     return replicate_scatter_pallas(values, vidx, ok, repl,
-                                    interpret=INTERPRET)
+                                    interpret=_interpret())
 
 
 def unpack_cols(buf: jnp.ndarray) -> jnp.ndarray:
     """Packed-shuffle unpack: (rows, lanes) -> (lanes, rows)."""
     if USE_REF:
         return ref.unpack_cols_ref(buf)
-    return unpack_cols_pallas(buf, interpret=INTERPRET)
+    return unpack_cols_pallas(buf, interpret=_interpret())
 
 
 def member_mask(keys: jnp.ndarray, heavy: jnp.ndarray) -> jnp.ndarray:
@@ -112,7 +112,7 @@ def member_mask(keys: jnp.ndarray, heavy: jnp.ndarray) -> jnp.ndarray:
     in the padded sorted heavy set."""
     if USE_REF:
         return ref.member_mask_ref(keys, heavy)
-    return member_mask_pallas(keys, heavy, interpret=INTERPRET)
+    return member_mask_pallas(keys, heavy, interpret=_interpret())
 
 
 def rle_expand(values: jnp.ndarray, starts: jnp.ndarray,
@@ -121,7 +121,7 @@ def rle_expand(values: jnp.ndarray, starts: jnp.ndarray,
     i ([starts[j], ends[j]) tile [0, n)). int64 bit-views."""
     if USE_REF:
         return ref.rle_expand_ref(values, starts, ends, n)
-    return rle_expand_pallas(values, starts, ends, n, interpret=INTERPRET)
+    return rle_expand_pallas(values, starts, ends, n, interpret=_interpret())
 
 
 def delta_unpack(z: jnp.ndarray, first: jnp.ndarray) -> jnp.ndarray:
@@ -129,7 +129,7 @@ def delta_unpack(z: jnp.ndarray, first: jnp.ndarray) -> jnp.ndarray:
     of the decoded deltas. z (n,) uint64, first (1,) uint64 -> int64."""
     if USE_REF:
         return ref.delta_unpack_ref(z, first)
-    return delta_unpack_pallas(z, first, interpret=INTERPRET)
+    return delta_unpack_pallas(z, first, interpret=_interpret())
 
 
 def bitunpack(words: jnp.ndarray, k: int, vpw: int, n: int,
@@ -138,7 +138,7 @@ def bitunpack(words: jnp.ndarray, k: int, vpw: int, n: int,
     + lo -> int64, trimmed to n rows."""
     if USE_REF:
         return ref.bitunpack_ref(words, k, vpw, n, lo)
-    return bitunpack_pallas(words, k, vpw, n, lo, interpret=INTERPRET)
+    return bitunpack_pallas(words, k, vpw, n, lo, interpret=_interpret())
 
 
 def dict_gather(values: jnp.ndarray, codes: jnp.ndarray) -> jnp.ndarray:
@@ -146,7 +146,7 @@ def dict_gather(values: jnp.ndarray, codes: jnp.ndarray) -> jnp.ndarray:
     out-of-range codes gather 0)."""
     if USE_REF:
         return ref.dict_gather_ref(values, codes)
-    return dict_gather_pallas(values, codes, interpret=INTERPRET)
+    return dict_gather_pallas(values, codes, interpret=_interpret())
 
 
 def flash_attention(q, k, v, causal: bool = True,
@@ -160,10 +160,10 @@ def flash_attention(q, k, v, causal: bool = True,
     return flash_attention_pallas(q, k, v, causal=causal, window=window,
                                   softcap=softcap, scale=scale,
                                   block_q=block_q, block_k=block_k,
-                                  interpret=INTERPRET)
+                                  interpret=_interpret())
 
 
 def rwkv6_scan(r, k, v, w, u, chunk: int = 64):
     if USE_REF:
         return ref.rwkv6_ref(r, k, v, w, u)
-    return rwkv6_pallas(r, k, v, w, u, chunk=chunk, interpret=INTERPRET)
+    return rwkv6_pallas(r, k, v, w, u, chunk=chunk, interpret=_interpret())

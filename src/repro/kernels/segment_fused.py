@@ -27,15 +27,17 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import words as W
 
-DEF_BLOCK_ROWS = 256      # rows per grid step
+
+DEF_BLOCK_ROWS = 512      # rows per grid step
 DEF_BLOCK_SEGS = 128      # segments per grid step (one MXU tile side)
 
 I32_MAX = jnp.iinfo(jnp.int32).max
 
 
 def _kernel(seg_ref, val_ref, key_ref, sum_ref, fidx_ref, fval_ref, *,
-            block_rows, block_segs):
+            block_rows, block_segs, n_words):
     sb = pl.program_id(0)           # segment-block index
     rb = pl.program_id(1)           # row-block index (fastest; accumulates)
 
@@ -45,12 +47,11 @@ def _kernel(seg_ref, val_ref, key_ref, sum_ref, fidx_ref, fval_ref, *,
         fidx_ref[...] = jnp.full_like(fidx_ref, I32_MAX)
         fval_ref[...] = jnp.zeros_like(fval_ref)
 
-    segs = seg_ref[...]             # (block_rows,)
+    segs = seg_ref[0, :]            # (block_rows,)
     vals = val_ref[...]             # (block_rows, d) f32
-    keys = key_ref[...]             # (block_rows, k) int64 bit-views
-    local = segs - sb * block_segs
-    onehot = (local[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (block_rows, block_segs), 1))
+    local = segs[:, None] - sb * block_segs
+    onehot = local == jax.lax.broadcasted_iota(
+        jnp.int32, (block_rows, block_segs), 1)
 
     # (block_segs, block_rows) @ (block_rows, d) on the MXU
     sum_ref[...] += jax.lax.dot_general(
@@ -59,13 +60,15 @@ def _kernel(seg_ref, val_ref, key_ref, sum_ref, fidx_ref, fval_ref, *,
 
     rows = rb * block_rows + jax.lax.broadcasted_iota(
         jnp.int32, (block_rows, block_segs), 0)
-    cand = jnp.min(jnp.where(onehot, rows, I32_MAX), axis=0)  # (block_segs,)
-    cur = fidx_ref[...][:, 0]
+    cand = jnp.min(jnp.where(onehot, rows, jnp.int32(I32_MAX)), axis=0)
+    cur = fidx_ref[0, :]
     upd = cand < cur
     hit = onehot & (rows == cand[None, :])    # the first row of each seg
-    fv = jnp.sum(jnp.where(hit[:, :, None], keys[:, None, :], 0), axis=0)
-    fidx_ref[...] = jnp.where(upd, cand, cur)[:, None]
-    fval_ref[...] = jnp.where(upd[:, None], fv, fval_ref[...])
+    for j in range(n_words):        # masked integer sum per key word
+        fv = jnp.sum(jnp.where(hit, key_ref[j, :][:, None], jnp.int32(0)),
+                     axis=0, dtype=jnp.int32)
+        fval_ref[j, :] = jnp.where(upd, fv, fval_ref[j, :])
+    fidx_ref[0, :] = jnp.where(upd, cand, cur)
 
 
 def segment_sum_first_pallas(values: jnp.ndarray, keys: jnp.ndarray,
@@ -75,40 +78,41 @@ def segment_sum_first_pallas(values: jnp.ndarray, keys: jnp.ndarray,
                              interpret: bool = True) -> tuple:
     """(sums (S, d) f32, firstidx (S,) i32, firstvals (S, k) i64) over
     sorted ``seg_ids``. Rows with seg_id outside [0, num_segments) are
-    dropped (the invalid-row sentinel convention)."""
+    dropped (the invalid-row sentinel convention). The int64 key
+    columns cross as rows of 32-bit words (``kernels.words``)."""
     n, d = values.shape
     k = keys.shape[1]
-    block_rows = min(block_rows, n)
-    block_segs = min(block_segs, num_segments)
-    n_pad = (-n) % block_rows
-    s_pad = (-num_segments) % block_segs
+    br = W.lanes_for(n, block_rows)
+    bs = W.lanes_for(num_segments, block_segs)
+    n_pad = (-n) % br
     if n_pad:
         values = jnp.pad(values, ((0, n_pad), (0, 0)))
-        keys = jnp.pad(keys, ((0, n_pad), (0, 0)))
-        seg_ids = jnp.pad(seg_ids, (0, n_pad), constant_values=-1)
-    S = num_segments + s_pad
-    n_tot = n + n_pad
-
-    grid = (S // block_segs, n_tot // block_rows)
+    seg = W.row(seg_ids.astype(jnp.int32), br, -1)
+    hi, lo = W.split64(keys)
+    words = jnp.pad(jnp.concatenate([hi, lo], axis=1).T,
+                    ((0, 0), (0, n_pad)))             # (2k, rows)
+    S = num_segments + (-num_segments) % bs
     sums, fidx, fvals = pl.pallas_call(
-        functools.partial(_kernel, block_rows=block_rows,
-                          block_segs=block_segs),
-        grid=grid,
+        functools.partial(_kernel, block_rows=br, block_segs=bs,
+                          n_words=2 * k),
+        grid=(S // bs, seg.shape[1] // br),
         in_specs=[
-            pl.BlockSpec((block_rows,), lambda sb, rb: (rb,)),
-            pl.BlockSpec((block_rows, d), lambda sb, rb: (rb, 0)),
-            pl.BlockSpec((block_rows, k), lambda sb, rb: (rb, 0)),
+            pl.BlockSpec((1, br), lambda sb, rb: (jnp.int32(0), rb)),
+            pl.BlockSpec((br, d), lambda sb, rb: (rb, jnp.int32(0))),
+            pl.BlockSpec((2 * k, br), lambda sb, rb: (jnp.int32(0), rb)),
         ],
         out_specs=[
-            pl.BlockSpec((block_segs, d), lambda sb, rb: (sb, 0)),
-            pl.BlockSpec((block_segs, 1), lambda sb, rb: (sb, 0)),
-            pl.BlockSpec((block_segs, k), lambda sb, rb: (sb, 0)),
+            pl.BlockSpec((bs, d), lambda sb, rb: (sb, jnp.int32(0))),
+            pl.BlockSpec((1, bs), lambda sb, rb: (jnp.int32(0), sb)),
+            pl.BlockSpec((2 * k, bs), lambda sb, rb: (jnp.int32(0), sb)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((S, d), values.dtype),
-            jax.ShapeDtypeStruct((S, 1), jnp.int32),
-            jax.ShapeDtypeStruct((S, k), keys.dtype),
+            jax.ShapeDtypeStruct((1, S), jnp.int32),
+            jax.ShapeDtypeStruct((2 * k, S), jnp.int32),
         ],
         interpret=interpret,
-    )(seg_ids.astype(jnp.int32), values, keys)
-    return sums[:num_segments], fidx[:num_segments, 0], fvals[:num_segments]
+    )(seg, values, words)
+    fvals = W.join64(fvals[:k].T, fvals[k:].T)
+    return sums[:num_segments], fidx[0, :num_segments], \
+        fvals[:num_segments]

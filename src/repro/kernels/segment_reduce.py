@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-DEF_BLOCK_ROWS = 512      # rows per grid step (8x MXU depth)
+DEF_BLOCK_ROWS = 1024     # rows per grid step (XLA tiles 1-D int32 by 1024)
 DEF_BLOCK_SEGS = 128      # segments per grid step (one MXU tile side)
 DEF_BLOCK_D = 128         # value lanes
 
@@ -75,9 +75,11 @@ def segment_reduce_pallas(values: jnp.ndarray, seg_ids: jnp.ndarray,
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_rows,), lambda sb, rb: (rb,)),
-            pl.BlockSpec((block_rows, d), lambda sb, rb: (rb, 0)),
+            pl.BlockSpec((block_rows, d),
+                         lambda sb, rb: (rb, jnp.int32(0))),
         ],
-        out_specs=pl.BlockSpec((block_segs, d), lambda sb, rb: (sb, 0)),
+        out_specs=pl.BlockSpec((block_segs, d),
+                               lambda sb, rb: (sb, jnp.int32(0))),
         out_shape=jax.ShapeDtypeStruct((S, d), values.dtype),
         interpret=interpret,
     )(seg_ids.astype(jnp.int32), values)

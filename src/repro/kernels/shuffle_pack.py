@@ -38,10 +38,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import words as W
+
 
 DEF_BLOCK_M = 128      # send-buffer slots per grid step
 DEF_BLOCK_SRC = 128    # source rows per grid step (accumulation axis)
 DEF_BLOCK_T = 256      # wire-buffer rows per transpose grid step
+DEF_BLOCK_N = 512      # keys per membership grid step (a lane multiple)
+
+_I32_MAX = jnp.iinfo(jnp.int32).max
 
 
 def _pack_kernel(idx_ref, ok_ref, val_ref, out_ref, *, block_m, block_src):
@@ -159,41 +164,46 @@ def replicate_scatter_pallas(values: jnp.ndarray, vidx: jnp.ndarray,
     return out[:m]
 
 
-def _member_kernel(keys_ref, heavy_ref, out_ref):
-    keys = keys_ref[...]            # (block_n,) int64 packed keys
-    heavy = heavy_ref[...]          # (m,) int64 sorted heavy set
-    i64_max = jnp.iinfo(jnp.int64).max
-    hit = (keys[:, None] == heavy[None, :]) & (heavy[None, :] != i64_max)
+def _member_kernel(kh_ref, kl_ref, hh_ref, hl_ref, out_ref):
+    kh = kh_ref[0, :]               # (block_n,) key words
+    kl = kl_ref[0, :]
+    hh = hh_ref[0, :]               # (m,) heavy-set words
+    hl = hl_ref[0, :]
+    h_real = ~((hh == _I32_MAX) & (hl == -1))
+    k_real = ~((kh == _I32_MAX) & (kl == -1))
+    hit = (kh[:, None] == hh[None, :]) & (kl[:, None] == hl[None, :]) \
+        & h_real[None, :]
     # int32 accumulation, not bool any: exact, and VPU-friendly
-    out_ref[...] = (jnp.sum(hit.astype(jnp.int32), axis=1) > 0) \
-        & (keys != i64_max)
+    out_ref[0, :] = ((jnp.sum(hit.astype(jnp.int32), axis=1,
+                              dtype=jnp.int32) > 0)
+                     & k_real).astype(jnp.int32)
 
 
 def member_mask_pallas(keys: jnp.ndarray, heavy: jnp.ndarray,
-                       block_n: int = DEF_BLOCK_M,
+                       block_n: int = DEF_BLOCK_N,
                        interpret: bool = True) -> jnp.ndarray:
     """out[i] = keys[i] in heavy (padding I64_MAX never matches, on
-    either side) — the skew-triple probe split."""
+    either side) — the skew-triple probe split. Keys and the heavy set
+    enter as int32 word rows (``kernels.words``)."""
     n = keys.shape[0]
-    block_n = min(block_n, n)
-    n_pad = (-n) % block_n
-    if n_pad:
-        keys = jnp.pad(keys, (0, n_pad),
-                       constant_values=jnp.iinfo(jnp.int64).max)
     m = heavy.shape[0]
-    grid = ((n + n_pad) // block_n,)
+    bn = W.lanes_for(n, block_n)
+    pad = (_I32_MAX, -1)            # the words of I64_MAX
+    kh, kl = (W.row(w, bn, f) for w, f in zip(W.split64(keys), pad))
+    hh, hl = (W.row(w, 128, f) for w, f in zip(W.split64(heavy), pad))
+    width = kh.shape[1]
+    key_spec = pl.BlockSpec((1, bn), lambda nb: (jnp.int32(0), nb))
+    heavy_spec = pl.BlockSpec((1, hh.shape[1]),
+                              lambda nb: (jnp.int32(0), jnp.int32(0)))
     out = pl.pallas_call(
         _member_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n,), lambda nb: (nb,)),
-            pl.BlockSpec((m,), lambda nb: (0,)),
-        ],
-        out_specs=pl.BlockSpec((block_n,), lambda nb: (nb,)),
-        out_shape=jax.ShapeDtypeStruct((n + n_pad,), jnp.bool_),
+        grid=(width // bn,),
+        in_specs=[key_spec, key_spec, heavy_spec, heavy_spec],
+        out_specs=key_spec,
+        out_shape=jax.ShapeDtypeStruct((1, width), jnp.int32),
         interpret=interpret,
-    )(keys, heavy)
-    return out[:n]
+    )(kh, kl, hh, hl)
+    return out[0, :n] != 0
 
 
 def _unpack_kernel(buf_ref, out_ref):

@@ -19,6 +19,5 @@ def make_production_mesh(*, multi_pod: bool = False):
 def make_query_mesh(n_partitions: int, axis: str = "data"):
     """1-D mesh for the distributed query engine (bags are row-sharded
     over pod x data; the model axis replicates — DESIGN.md §5)."""
-    import numpy as np
-    devs = jax.devices()[:n_partitions]
-    return jax.sharding.Mesh(np.array(devs), (axis,))
+    from repro.exec.dist import device_mesh_1d
+    return device_mesh_1d(n_partitions, axis)

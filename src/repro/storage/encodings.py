@@ -121,7 +121,11 @@ def unpack_members(enc: dict, blob: np.ndarray) -> Dict[str, np.ndarray]:
     for name, dts, count, off in enc["members"]:
         dt = np.dtype(dts)
         nb = int(count) * dt.itemsize
-        out[name] = blob[int(off):int(off) + nb].view(dt)
+        piece = blob[int(off):int(off) + nb]
+        if piece.nbytes != nb:
+            raise ValueError(f"member {name!r} holds {piece.nbytes} of "
+                             f"its {nb} bytes (torn blob)")
+        out[name] = piece.view(dt)
     return out
 
 

@@ -63,13 +63,13 @@ def _count(name: str, n: int = 1) -> None:
     _METRICS.inc("storage." + name, n)
 
 
-def _decode_device(enc: dict, blob: np.ndarray) -> np.ndarray:
-    """Decode one encoded chunk blob through the Pallas kernels. All
-    kernels work on int64 bit-views (floats cross as raw bits), so the
-    result is bit-for-bit ``encodings.decode_chunk``."""
+def _decode_device(enc: dict, m: Dict[str, np.ndarray]) -> np.ndarray:
+    """Decode one encoded chunk's members (``encodings.unpack_members``)
+    through the Pallas kernels. All kernels work on int64 bit-views
+    (floats cross as raw bits), so the result is bit-for-bit
+    ``encodings.decode_chunk``."""
     from repro.kernels import ops as K
     dtype = np.dtype(enc["dtype"])
-    m = E.unpack_members(enc, blob)
 
     def to_i64(v: np.ndarray) -> np.ndarray:
         return v.view(np.int64) if v.dtype.kind == "f" \
@@ -243,10 +243,14 @@ class StoredPart:
             with _span("decode", part=meta.name, col=col, chunk=i,
                        codec=enc.get("codec")):
                 t0 = time.perf_counter()
+                # a blob that does not parse is a fault of the data; a
+                # kernel that fails to compile or run is not, and raises
+                # as itself
                 try:
-                    a = _decode_device(enc, np.asarray(a)) \
-                        if DEVICE_DECODE \
-                        else E.decode_chunk(enc, np.asarray(a))
+                    if DEVICE_DECODE:
+                        members = E.unpack_members(enc, np.asarray(a))
+                    else:
+                        a = E.decode_chunk(enc, np.asarray(a))
                 except ChunkCorruptionError:
                     raise
                 except Exception as e:
@@ -254,6 +258,8 @@ class StoredPart:
                         f"{meta.name}.{col} chunk {i}: "
                         f"{enc.get('codec')} decode failed ({e!r})"
                     ) from e
+                if DEVICE_DECODE:
+                    a = _decode_device(enc, members)
                 if count:
                     _count("decode_us",
                            int((time.perf_counter() - t0) * 1e6))
