@@ -170,9 +170,10 @@ def run_dist(n: int = 4000):
 
 def run(n: int = 20000, n_parts: int = 512, pallas_n: int = 1000,
         dist_n: int = 4000):
-    # pallas variant runs tiny on CPU: interpret mode executes the grid
-    # as a Python loop, so it only demonstrates wiring here; the real
-    # number needs a TPU (kernels.ops.detect_backend flips INTERPRET)
+    # pallas variant runs tiny: off the TPU, interpret mode executes the
+    # grid as a Python loop, so it only demonstrates wiring. On a TPU its
+    # fk_join reaches gather_rows, which Mosaic refuses, so the variant
+    # raises there (ROADMAP Speed 2)
     for label, order_aware, use_kernel, nn, iters in (
             ("fused", True, False, n, 3),
             ("unfused", False, False, n, 3),

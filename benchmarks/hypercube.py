@@ -52,7 +52,7 @@ from repro.core import nrc as N
 from repro.core import skew as SKM
 from repro.core.plans import MultiJoinP, collect_plan_params, _walk_plan
 from repro.data.generators import TPCH_TYPES, gen_tpch
-from repro.exec.dist import device_mesh_1d
+from repro.exec.dist import device_mesh_1d, receive_imbalance
 from repro.storage import StorageCatalog, table_stats
 from benchmarks.common import CATALOG
 
@@ -60,19 +60,6 @@ SMOKE = %(smoke)d
 PN = 8
 WARM_ITERS = 3 if SMOKE else 8
 mesh = device_mesh_1d(PN)
-
-
-def imbalance(metrics, floor=64):
-    '''Worst max/mean receive load over the exchange sites that moved
-    at least ``floor`` rows (tiny metadata exchanges excluded).'''
-    worst = 1.0
-    for k, v in metrics.items():
-        if k.startswith("part_rows_") and v >= floor:
-            s = k.rsplit("_", 1)[1]
-            worst = max(worst,
-                        metrics.get(f"part_max_{s}", 0) * PN / max(v, 1))
-    return worst
-
 
 db = gen_tpch(scale=48 if SMOKE else 192, skew=2.0, seed=0)
 types = {k: TPCH_TYPES[k] for k in ("Lineitem", "Part", "Orders")}
@@ -136,7 +123,7 @@ for mode in ("hypercube", "cascade"):
     out.append(dict(
         kind="mode", mode=mode, seconds=warm, cold_seconds=cold,
         ok=I.bags_equal(direct, rows_of(res)), multijoin=mj,
-        imbalance=imbalance(m),
+        imbalance=receive_imbalance(m, PN, floor=64),
         collectives=int(m["shuffle_collectives"]),
         hc_exchanges=int(m.get("hypercube_exchanges", 0)),
         shuffle_rows=int(m["shuffle_rows"]),

@@ -134,7 +134,7 @@ from repro.core import nrc as N
 from repro.core import skew as SKM
 from repro.core.plans import SkewJoinP, _walk_plan, collect_plan_params
 from repro.data.generators import TPCH_TYPES, gen_tpch
-from repro.exec.dist import device_mesh_1d
+from repro.exec.dist import device_mesh_1d, receive_imbalance
 from repro.serve import QueryService
 from repro.storage import StorageCatalog, table_stats
 from benchmarks.common import CATALOG, materialize_nested_input, \
@@ -144,18 +144,6 @@ SMOKE = %(smoke)d
 PN = 8
 WARM_ITERS = 3 if SMOKE else 5
 mesh = device_mesh_1d(PN)
-
-
-def imbalance(metrics, floor=64):
-    '''Worst max/mean receive load over the exchange sites that moved
-    at least ``floor`` rows (tiny metadata exchanges excluded).'''
-    worst = 1.0
-    for k, v in metrics.items():
-        if k.startswith("part_rows_") and v >= floor:
-            s = k.rsplit("_", 1)[1]
-            worst = max(worst,
-                        metrics.get(f"part_max_{s}", 0) * PN / max(v, 1))
-    return worst
 
 
 def n_skew_nodes(cp):
@@ -211,7 +199,8 @@ for zipf in sweep:
         out.append(dict(
             kind="mode", zipf=zipf, mode=mode, seconds=warm,
             cold_seconds=cold, ok=I.bags_equal(direct, rows_of(res)),
-            skew_nodes=n_skew_nodes(cp), imbalance=imbalance(m),
+            skew_nodes=n_skew_nodes(cp),
+            imbalance=receive_imbalance(m, PN, floor=64),
             shuffle_rows=int(m["shuffle_rows"]),
             collectives=int(m["shuffle_collectives"]),
             overflow=int(m["overflow_rows"]),

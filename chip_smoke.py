@@ -51,6 +51,7 @@ import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 SF1_ORDERS = 1_500_000       # TPC-H scale factor 1
+CHUNK_ROWS = 1 << 16         # rows per stored chunk
 THRESHOLDS = (25.0, 50.0, 75.0)
 ORACLE_SCALE = 200
 WARM_CALLS = 5
@@ -216,6 +217,19 @@ def traces() -> int:
     return int(CG.TRACE_STATS.get("traces", 0))
 
 
+def cache_events() -> tuple:
+    """(hits, misses) of the persistent compile cache so far."""
+    from repro.obs.metrics import REGISTRY
+    return (int(REGISTRY.get("compile_cache.hits")),
+            int(REGISTRY.get("compile_cache.misses")))
+
+
+def cache_since(before: tuple) -> str:
+    hits, misses = cache_events()
+    return (f"compile cache {hits - before[0]} hits, "
+            f"{misses - before[1]} misses")
+
+
 def manifest(program, types):
     from repro.core import materialization as M
     return M.shred_program(program, types,
@@ -235,7 +249,7 @@ def write_dataset(root: str, name: str, db: dict, types: dict,
 # one chip
 # ---------------------------------------------------------------------------
 
-def run_one_chip(args, store: str) -> None:
+def run_one_chip(args, store: str, chunk_rows: int = CHUNK_ROWS) -> None:
     import jax
     from repro.core import interpreter as I
     from repro.data.generators import TPCH_TYPES, gen_tpch
@@ -252,7 +266,7 @@ def run_one_chip(args, store: str) -> None:
     t0 = time.perf_counter()
     db = gen_tpch(scale=args.scale, skew=0.0, seed=args.seed)
     t1 = time.perf_counter()
-    ds = write_dataset(store, "tpch", db, types, args.chunk_rows)
+    ds = write_dataset(store, "tpch", db, types, chunk_rows)
     t2 = time.perf_counter()
     ref = columns(db)
     del db
@@ -267,6 +281,7 @@ def run_one_chip(args, store: str) -> None:
     # (a) flat-to-nested, depth 2
     prog_a = query_a()
     man_a = manifest(prog_a, types)
+    cache0 = cache_events()
     out, cold = timed(lambda: svc.execute_stored(prog_a, ds))
     want = ref_a(ref)
     got = got_a(out, man_a)
@@ -285,11 +300,13 @@ def run_one_chip(args, store: str) -> None:
     same("(a) warm", got_a(out, man_a)["items"], want["items"])
     log(f"(a) cold_ms {cold:.3f} warm_ms median "
         f"{statistics.median(warm):.3f} over {len(warm)} calls "
-        f"{[round(w, 3) for w in warm]}, warm retraces 0")
+        f"{[round(w, 3) for w in warm]}, warm retraces 0; "
+        f"{cache_since(cache0)}")
 
     # (b) the join chain, several thresholds
     man_b = manifest(query_b(THRESHOLDS[0]), types)
     wants = {t: ref_b(ref, t) for t in THRESHOLDS}
+    cache0 = cache_events()
     out, cold = timed(lambda: svc.execute_stored(query_b(THRESHOLDS[0]),
                                                  ds))
     same(f"(b) t={THRESHOLDS[0]}", got_b(out, man_b), wants[THRESHOLDS[0]])
@@ -319,13 +336,14 @@ def run_one_chip(args, store: str) -> None:
         f"{[round(w, 3) for w in warm]} at thresholds "
         f"{list(THRESHOLDS)}; runtime submit ms median "
         f"{statistics.median(served):.3f} over {len(served)} (ok, not "
-        f"degraded); warm retraces 0")
+        f"degraded); warm retraces 0; {cache_since(cache0)}")
     log("(b) groups per threshold: " + json.dumps(
         {str(t): int(wants[t][0].size) for t in THRESHOLDS})
         + " == reference")
     del ref, out
 
     # the interpreter oracle, on a small copy
+    cache0 = cache_events()
     small = gen_tpch(scale=ORACLE_SCALE, skew=0.0, seed=args.seed)
     ds_s = write_dataset(store, "tpch_small", small, types, 64)
     inputs = {k: small[k] for k in types}
@@ -343,31 +361,20 @@ def run_one_chip(args, store: str) -> None:
                                       man_a)["items"],
          ref_a(cols_s)["items"])
     log(f"oracle: (a) and (b) at {len(THRESHOLDS)} thresholds equal the "
-        f"interpreter at scale {ORACLE_SCALE}")
+        f"interpreter at scale {ORACLE_SCALE}; {cache_since(cache0)}")
 
 
 # ---------------------------------------------------------------------------
 # four chips
 # ---------------------------------------------------------------------------
 
-def imbalance(metrics: dict, n: int, floor: int = 64) -> float:
-    """Worst max/mean receive load over the exchange sites that moved
-    at least ``floor`` rows."""
-    worst = 1.0
-    for k, v in metrics.items():
-        if k.startswith("part_rows_") and v >= floor:
-            s = k.rsplit("_", 1)[1]
-            worst = max(worst, metrics.get(f"part_max_{s}", 0) * n
-                        / max(v, 1))
-    return worst
-
-
-def run_four_chips(args, store: str, devices) -> None:
+def run_four_chips(args, store: str, devices,
+                   chunk_rows: int = CHUNK_ROWS) -> None:
     import jax
     from repro.core import skew as SKM
     from repro.core.plans import MultiJoinP, SkewJoinP, _walk_plan
     from repro.data.generators import TPCH_TYPES, gen_tpch
-    from repro.exec.dist import device_mesh_1d
+    from repro.exec.dist import device_mesh_1d, receive_imbalance
     from repro.serve import QueryService
     from repro.storage import table_stats
     from benchmarks.common import CATALOG
@@ -379,7 +386,7 @@ def run_four_chips(args, store: str, devices) -> None:
         f"{n}-chip mesh; cut: x{args.scale / SF1_ORDERS:g} of TPC-H SF1")
     t0 = time.perf_counter()
     db = gen_tpch(scale=args.scale, skew=2.0, seed=args.seed)
-    ds = write_dataset(store, "tpch_zipf2", db, types, args.chunk_rows)
+    ds = write_dataset(store, "tpch_zipf2", db, types, chunk_rows)
     ref = columns(db)
     del db
     log(f"host: generate+write {time.perf_counter() - t0:.1f} s")
@@ -426,13 +433,14 @@ def run_four_chips(args, store: str, devices) -> None:
         if traces() != t_before:
             raise AssertionError(f"{node.__name__}: warm calls retraced")
         m = svc.last_metrics
+        worst = receive_imbalance(m, n, floor=64)
         log(f"{node.__name__} x{lowered} on {n} chips: bit-for-bit equal "
             f"to one chip at {len(THRESHOLDS)} thresholds; collectives "
             f"{m['shuffle_collectives']}, exchanges {m['exchanges']} "
             f"(elided {m['exchanges_elided']}), shipped rows "
             f"{m['shuffle_rows']}, overflow {m['overflow_rows']}, worst "
-            f"receive imbalance {imbalance(m, n):.3f}; cold_ms "
-            f"{cold:.3f}, warm_ms median {statistics.median(warm):.3f}")
+            f"receive imbalance {worst:.3f}; cold_ms {cold:.3f}, warm_ms "
+            f"median {statistics.median(warm):.3f}")
 
 
 # ---------------------------------------------------------------------------
@@ -444,7 +452,6 @@ def main(argv=None) -> None:
                     help="orders to generate (default 1,500,000 = TPC-H "
                          "SF1 on one chip, 375,000 on four)")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--chunk-rows", type=int, default=1 << 16)
     args = ap.parse_args(argv)
     if args.scale is None:
         args.scale = SF1_ORDERS if args.chips == 1 else SF1_ORDERS // 4
@@ -482,8 +489,7 @@ def main(argv=None) -> None:
 
     stats = devices[0].memory_stats() or {}
     log(f"peak_bytes_in_use: {stats.get('peak_bytes_in_use')}")
-    log(f"compile cache: {compile_cache.EVENTS['hits']} hits, "
-        f"{compile_cache.EVENTS['misses']} misses")
+    log(f"{cache_since((0, 0))} in all")
     print(json.dumps({"ok": True, "device": {
         "platform": platform, "kind": devices[0].device_kind,
         "count": len(devices)}}), flush=True)

@@ -8,26 +8,27 @@ The directory is ``JAX_COMPILATION_CACHE_DIR`` when that is set (JAX
 reads it itself), else the fixed ``<checkout>/.jax_cache``. The path is
 part of a cache entry's key, so it never depends on a temporary name, a
 process id or the time.
+
+After ``enable``, the metrics registry counts ``compile_cache.hits``
+(reads that found an executable) and ``compile_cache.misses``
+(compiles written to the cache).
 """
 
 from __future__ import annotations
 
 import os
 
-EVENTS = {"hits": 0, "misses": 0}
-"""Persistent-cache reads that found an executable (``hits``) and
-compiles written to the cache (``misses``) since ``enable``."""
+from repro.obs.metrics import REGISTRY
 
-_HIT = "/jax/compilation_cache/cache_hits"
-_MISS = "/jax/compilation_cache/cache_misses"
+_EVENTS = {"/jax/compilation_cache/cache_hits": "compile_cache.hits",
+           "/jax/compilation_cache/cache_misses": "compile_cache.misses"}
 _registered = False
 
 
 def _count(event: str, **_kw) -> None:
-    if event == _HIT:
-        EVENTS["hits"] += 1
-    elif event == _MISS:
-        EVENTS["misses"] += 1
+    name = _EVENTS.get(event)
+    if name is not None:
+        REGISTRY.inc(name)
 
 
 def enable(checkout: str) -> str:

@@ -762,6 +762,25 @@ def _merge_host_stats(metrics: Dict[str, int],
     return metrics
 
 
+def receive_imbalance(metrics: Optional[Dict[str, int]], n_partitions: int,
+                      floor: int = 1) -> float:
+    """Worst receive-load imbalance of one distributed execute: over the
+    exchange sites that moved at least ``floor`` rows, the most rows one
+    partition received (``part_max_<site>``) over the mean
+    (``part_rows_<site>`` / ``n_partitions``). 1.0 with no such site or
+    a single partition."""
+    worst = 1.0
+    if not metrics or n_partitions <= 1:
+        return worst
+    for k, v in metrics.items():
+        if not k.startswith("part_max_"):
+            continue
+        total = metrics.get("part_rows_" + k[len("part_max_"):], 0)
+        if total >= max(floor, 1):
+            worst = max(worst, float(v) * n_partitions / float(total))
+    return worst
+
+
 class DistRunner:
     """A compiled distributed program with its capacity plan resolved.
 

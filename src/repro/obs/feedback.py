@@ -69,16 +69,8 @@ class StatsFeedback:
                        n_partitions: int) -> float:
         """Fold one execution's device metrics into the per-family
         imbalance history; returns the measured ratio."""
-        worst = 1.0
-        if metrics and n_partitions > 1:
-            for k, v in metrics.items():
-                if not k.startswith("part_max_"):
-                    continue
-                site = k[len("part_max_"):]
-                total = metrics.get(f"part_rows_{site}", 0)
-                if total:
-                    worst = max(worst,
-                                float(v) * n_partitions / float(total))
+        from repro.exec.dist import receive_imbalance
+        worst = receive_imbalance(metrics, n_partitions)
         cur = self.imbalance_x100.get(family, 100)
         self.imbalance_x100[family] = max(cur, int(worst * 100))
         return worst

@@ -52,6 +52,7 @@ from repro.columnar.table import FlatBag
 from repro.errors import (CapacityOverflowError, CircuitOpenError,
                           DeadlineExceeded, ExchangeError, FooterError,
                           ReproError, ShedError, StorageError)
+from repro.exec.dist import receive_imbalance
 from repro.faults import FAULTS
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import span as _span
@@ -609,7 +610,7 @@ class ServingRuntime:
         Beyond threshold, future calls of the family pin to the local
         twin — the distributed placement is pathological for its key
         distribution (Beame/Koutris/Suciu's skew regime)."""
-        ratio = self._imbalance_ratio(svc.last_metrics, svc.mesh.size)
+        ratio = receive_imbalance(svc.last_metrics, svc.mesh.size)
         rule = FAULTS.hit("dist.imbalance", family=_family_id(key))
         if rule is not None and rule.kind == "inflate":
             ratio *= float(rule.arg or 10.0)
@@ -619,20 +620,6 @@ class ServingRuntime:
             if fid not in self._degraded_families:
                 self._degraded_families.add(fid)
                 self.stats["degraded_imbalance"] += 1
-
-    @staticmethod
-    def _imbalance_ratio(metrics: Optional[dict], nparts: int) -> float:
-        if not metrics or nparts <= 1:
-            return 1.0
-        worst = 1.0
-        for k, v in metrics.items():
-            if not k.startswith("part_max_"):
-                continue
-            site = k[len("part_max_"):]
-            total = metrics.get(f"part_rows_{site}", 0)
-            if total:
-                worst = max(worst, float(v) * nparts / float(total))
-        return worst
 
     # -- crash recovery -----------------------------------------------------
     def _record(self, req: QueryRequest, key: tuple) -> None:

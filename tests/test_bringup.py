@@ -52,8 +52,8 @@ def test_chip_smoke_refuses_a_lone_directory(tmp_path):
 
 
 def test_chip_smoke_one_chip_phases_on_cpu(tmp_path):
-    args = argparse.Namespace(chips=1, scale=300, seed=3, chunk_rows=128)
-    S.run_one_chip(args, str(tmp_path))
+    args = argparse.Namespace(chips=1, scale=300, seed=3)
+    S.run_one_chip(args, str(tmp_path), chunk_rows=128)
 
 
 def test_chip_smoke_four_chip_phases_on_virtual_devices(tmp_path):
@@ -61,9 +61,9 @@ def test_chip_smoke_four_chip_phases_on_virtual_devices(tmp_path):
         import argparse, sys
         sys.path[:0] = [{os.path.join(ROOT, 'src')!r}, {ROOT!r}]
         import jax, repro, chip_smoke as S
-        args = argparse.Namespace(chips=4, scale=600, seed=1,
-                                  chunk_rows=128)
-        S.run_four_chips(args, {str(tmp_path)!r}, jax.devices())
+        args = argparse.Namespace(chips=4, scale=600, seed=1)
+        S.run_four_chips(args, {str(tmp_path)!r}, jax.devices(),
+                         chunk_rows=128)
         print("FOUR-CHIP REHEARSAL OK")
     """)
     res = _run(None, {"XLA_FLAGS":
@@ -98,6 +98,24 @@ def test_mesh_refuses_to_shrink(make):
     assert fn(n).size == n
     with pytest.raises(ValueError, match="needs"):
         fn(n + 1)
+
+
+_SITES = {"part_max_j0": 30, "part_rows_j0": 60,     # 2.0 at P=4
+          "part_max_j1": 10, "part_rows_j1": 10,     # 4.0, a tiny site
+          "part_max_j2": 5}                          # no row count
+
+
+@pytest.mark.parametrize("metrics,n,floor,want", [
+    (_SITES, 4, 1, 4.0),
+    (_SITES, 4, 64, 1.0),
+    (_SITES, 4, 20, 2.0),
+    (_SITES, 1, 1, 1.0),
+    ({}, 4, 1, 1.0),
+    (None, 4, 1, 1.0),
+])
+def test_receive_imbalance(metrics, n, floor, want):
+    from repro.exec.dist import receive_imbalance
+    assert receive_imbalance(metrics, n, floor=floor) == want
 
 
 def test_kernels_interpret_only_off_the_tpu(monkeypatch):
@@ -201,6 +219,32 @@ def test_compile_cache_directory(tmp_path, from_env):
     want = env_dir if from_env else str(tmp_path / ".jax_cache")
     assert f"PATH {want}" in res.stdout
     assert f"DIR {want}" in res.stdout
+
+
+def test_compile_cache_counts_in_the_metrics_registry(tmp_path):
+    """A compile writes the cache (a miss); the same program compiled
+    again after the in-memory caches are cleared reads it (a hit)."""
+    code = textwrap.dedent(f"""
+        import sys; sys.path.insert(0, 'src')
+        import jax, jax.numpy as jnp
+        from repro import compile_cache
+        from repro.obs.metrics import REGISTRY
+        compile_cache.enable({str(tmp_path)!r})
+        f = lambda x: jnp.sort(x * 3 + 1)
+        jax.jit(f)(jnp.arange(64)).block_until_ready()
+        print('AFTER1', REGISTRY.get('compile_cache.hits'),
+              REGISTRY.get('compile_cache.misses'))
+        jax.clear_caches()
+        jax.jit(f)(jnp.arange(64)).block_until_ready()
+        print('AFTER2', REGISTRY.get('compile_cache.hits'),
+              REGISTRY.get('compile_cache.misses'))
+    """)
+    res = _run(None, code=code)
+    assert res.returncode == 0, res.stderr
+    after = {ln.split()[0]: tuple(map(int, ln.split()[1:]))
+             for ln in res.stdout.splitlines() if ln.startswith("AFTER")}
+    assert after["AFTER1"][0] == 0 and after["AFTER1"][1] >= 1
+    assert after["AFTER2"][0] >= 1 and after["AFTER2"][1] == after["AFTER1"][1]
 
 
 # -- the Zipf generator ------------------------------------------------------
