@@ -17,6 +17,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field as dc_field
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.columnar.table import FlatBag
@@ -433,14 +434,20 @@ def _scan(env: Dict[str, FlatBag], name: str, alias: str,
 
 def eval_plan(p: Plan, env: Dict[str, FlatBag],
               s: Optional[ExecSettings] = None) -> FlatBag:
+    """Evaluate one plan node (and its subtree). The node runs under
+    ``jax.named_scope(<plan node class>)``: trace-time metadata only,
+    so the innermost plan scope in every compiled op's HLO ``op_name``
+    names the operator that emitted it, while no instruction, result
+    bit or retrace changes."""
     s = s or ExecSettings()
-    if s.explain is not None:
-        # EXPLAIN ANALYZE: the recorder wraps every operator evaluation
-        # (timing + metric deltas + row counts) and calls back into
-        # _eval_plan_node; recursive child evaluations re-enter here,
-        # so the whole subtree is recorded
-        return s.explain.record(p, env, s, _eval_plan_node)
-    return _eval_plan_node(p, env, s)
+    with jax.named_scope(type(p).__name__):
+        if s.explain is not None:
+            # EXPLAIN ANALYZE: the recorder wraps every operator
+            # evaluation (timing + metric deltas + row counts) and calls
+            # back into _eval_plan_node; recursive child evaluations
+            # re-enter here, so the whole subtree is recorded
+            return s.explain.record(p, env, s, _eval_plan_node)
+        return _eval_plan_node(p, env, s)
 
 
 def _eval_plan_node(p: Plan, env: Dict[str, FlatBag],

@@ -157,8 +157,10 @@ class DistContext:
                  key: Optional[jnp.ndarray] = None) -> FlatBag:
         """Hash-repartition by key (span-traced wrapper; see
         ``_exchange``). The span fires at trace time — host-side only,
-        so warm jitted calls are untouched."""
-        with _span("exchange", keys=tuple(key_cols), site=self._n_sites):
+        so warm jitted calls are untouched; the ``exchange`` named scope
+        labels the compiled ops it emits."""
+        with _span("exchange", keys=tuple(key_cols), site=self._n_sites), \
+                jax.named_scope("exchange"):
             return self._exchange(bag, key_cols, keep, key)
 
     def _exchange(self, bag: FlatBag, key_cols: Sequence[str],
@@ -331,7 +333,8 @@ class DistContext:
     # -- broadcast (all_gather) -----------------------------------------
     def gather_all(self, bag: FlatBag,
                    keep: Optional[jnp.ndarray] = None) -> FlatBag:
-        with _span("broadcast", cols=bag.columns):
+        with _span("broadcast", cols=bag.columns), \
+                jax.named_scope("broadcast"):
             return self._gather_all(bag, keep)
 
     def _gather_all(self, bag: FlatBag,
@@ -496,9 +499,10 @@ class DistContext:
                    stages, shares: Sequence[int], rel_routes,
                    dim_heavy: Sequence[Optional[jnp.ndarray]],
                    use_kernel: bool = False) -> FlatBag:
-        """Span-traced wrapper; see ``_multi_join``."""
+        """Span-traced wrapper (compiled ops under the ``hypercube``
+        named scope); see ``_multi_join``."""
         with _span("exchange", kind="hypercube", shares=tuple(shares),
-                   site=self._n_sites):
+                   site=self._n_sites), jax.named_scope("hypercube"):
             return self._multi_join(spine, rights, stages, shares,
                                     rel_routes, dim_heavy, use_kernel)
 
@@ -807,7 +811,8 @@ class DistRunner:
         if self.params is None:
             assert params is None, (
                 "program compiled without runtime parameters")
-            out, metrics = self._sm(env)
+            with _span("query.dispatch"):
+                out, metrics = self._sm(env)
         else:
             p = dict(self.params)
             if params:
@@ -816,10 +821,16 @@ class DistRunner:
                     f"unknown parameter(s) {sorted(unknown)}; this "
                     f"program binds {sorted(p)}")
                 p.update(params)
-            out, metrics = self._sm(env, {k: jnp.asarray(v)
-                                          for k, v in p.items()})
-        return out, _merge_host_stats(
-            {k: int(v) for k, v in metrics.items()}, self.stats)
+            p = {k: jnp.asarray(v) for k, v in p.items()}
+            with _span("query.dispatch"):
+                out, metrics = self._sm(env, p)
+        # the meters' host reads wait for the program; the wait is a
+        # span of its own, and the reads then cost only their copies
+        with _span("dist.device_wait"):
+            jax.block_until_ready(metrics)
+        with _span("dist.meters"):
+            host = {k: int(v) for k, v in metrics.items()}
+        return out, _merge_host_stats(host, self.stats)
 
 
 def shard_program(fn: Callable, mesh: Mesh, axis: str = "data",

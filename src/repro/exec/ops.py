@@ -136,13 +136,15 @@ def _lexsort(bag: FlatBag, cols: Tuple[str, ...]) -> FlatBag:
     """Sort rows by (invalid-last, cols lexicographic). The result
     delivers ``sorted_by = cols`` with ``invalid_last``."""
     _count("lexsort")
-    keys = _key_arrays(bag, cols)
-    order = jnp.lexsort(tuple(reversed(keys)) + (~bag.valid,))
-    data = {n: a[order] for n, a in bag.data.items()}
+    with jax.named_scope("sort"):
+        keys = _key_arrays(bag, cols)
+        order = jnp.lexsort(tuple(reversed(keys)) + (~bag.valid,))
+        data = {n: a[order] for n, a in bag.data.items()}
+        valid = bag.valid[order]
     props = PhysicalProps(sorted_by=cols, invalid_last=True,
                           partitioning=_part_if(bag, bag.data)) \
         if ORDER_AWARE else None
-    return FlatBag(data, bag.valid[order], props)
+    return FlatBag(data, valid, props)
 
 
 def _presorted_seg_ids(bag: FlatBag, cols: Tuple[str, ...]) -> jnp.ndarray:
@@ -186,7 +188,8 @@ def _segments(bag: FlatBag, key_cols: Sequence[str]
         _count("sort_skipped")
     else:
         sbag = _lexsort(bag, cols)
-    seg_id = _presorted_seg_ids(sbag, cols)
+    with jax.named_scope("segment"):
+        seg_id = _presorted_seg_ids(sbag, cols)
     if ORDER_AWARE and _cache_ok(sbag, seg_id):
         sbag.props.seg_cache[cols] = seg_id
     return sbag, seg_id
@@ -268,8 +271,9 @@ def sum_by(bag: FlatBag, key_cols: Sequence[str], val_cols: Sequence[str],
     any prefix of the keys reuses this sort."""
     key_cols, val_cols = tuple(key_cols), tuple(val_cols)
     sbag, seg_id = _segments(bag, key_cols)
-    exists, out_valid, firsts, summed = _segment_firsts(
-        sbag, seg_id, key_cols, use_kernel, val_cols)
+    with jax.named_scope("segment"):
+        exists, out_valid, firsts, summed = _segment_firsts(
+            sbag, seg_id, key_cols, use_kernel, val_cols)
     data = dict(firsts)
     data.update(summed)
     props = None
@@ -284,8 +288,10 @@ def dedup(bag: FlatBag, cols: Optional[Sequence[str]] = None) -> FlatBag:
     """Keep one representative row per distinct value of ``cols``."""
     cols = tuple(cols or bag.columns)
     sbag, seg_id = _segments(bag, cols)
-    prev = jnp.concatenate([jnp.full((1,), -1, seg_id.dtype), seg_id[:-1]])
-    keep = (seg_id != prev) & sbag.valid
+    with jax.named_scope("segment"):
+        prev = jnp.concatenate([jnp.full((1,), -1, seg_id.dtype),
+                                seg_id[:-1]])
+        keep = (seg_id != prev) & sbag.valid
     props = None
     if ORDER_AWARE:
         props = PhysicalProps(key_cache=dict(sbag.props.key_cache),
@@ -323,8 +329,9 @@ def _build_side(right: FlatBag, right_on: Tuple[str, ...]
         srk = rkey
     else:
         _count("build_argsort")
-        order_r = jnp.argsort(rkey)
-        srk = rkey[order_r]
+        with jax.named_scope("sort"):
+            order_r = jnp.argsort(rkey)
+            srk = rkey[order_r]
     if ORDER_AWARE and _cache_ok(right, srk):
         right.props.build_cache[right_on] = (order_r, srk)
     return order_r, srk
@@ -410,18 +417,20 @@ def fk_join(left: FlatBag, right: FlatBag, left_on: Sequence[str],
     order_r, srk = _build_side(right, right_on)
     lkey = pack_keys(left, left_on)
 
-    if use_kernel:
-        from repro.kernels import ops as kops
-        pos, _ = kops.merge_positions(srk, lkey)
-    else:
-        pos = jnp.searchsorted(srk, lkey)
-    pos_c = jnp.clip(pos, 0, cap_r - 1)
-    ordg, srkg = _gather_columns([order_r, srk], pos_c, use_kernel)
-    ridx = ordg
+    with jax.named_scope("search"):
+        if use_kernel:
+            from repro.kernels import ops as kops
+            pos, _ = kops.merge_positions(srk, lkey)
+        else:
+            pos = jnp.searchsorted(srk, lkey)
     rnames = [n for n in right.data
               if not (right_prefix + n in left.data and n in right_on)]
-    gathered = _gather_columns(
-        [right.data[n] for n in rnames] + [right.valid], ridx, use_kernel)
+    with jax.named_scope("gather"):
+        pos_c = jnp.clip(pos, 0, cap_r - 1)
+        ridx, srkg = _gather_columns([order_r, srk], pos_c, use_kernel)
+        gathered = _gather_columns(
+            [right.data[n] for n in rnames] + [right.valid], ridx,
+            use_kernel)
     rvalid = gathered[-1]
     matched = (srkg == lkey) & rvalid & left.valid
 
@@ -466,41 +475,45 @@ def general_join(left: FlatBag, right: FlatBag, left_on: Sequence[str],
     cap_r = right.capacity
     order_r, srk = _build_side(right, right_on)
     lkey = pack_keys(left, left_on)
-    if use_kernel:
-        from repro.kernels import ops as kops
-        lo, hi = kops.merge_positions(srk, lkey)
-    else:
-        lo = jnp.searchsorted(srk, lkey, side="left")
-        hi = jnp.searchsorted(srk, lkey, side="right")
-    cnt = jnp.where(left.valid, hi - lo, 0)
-    if how == "left_outer":
-        cnt = jnp.where(left.valid & (cnt == 0), 1, cnt)
-    offs = jnp.cumsum(cnt)                      # inclusive
-    start = offs - cnt
-    total = offs[-1]
-
-    j = jnp.arange(out_capacity)
-    if use_kernel:
-        from repro.kernels import ops as kops
-        _, li = kops.merge_positions(offs, j)
-    else:
-        li = jnp.searchsorted(offs, j, side="right")
-    li_c = jnp.clip(li, 0, left.capacity - 1)
-    lgather = _gather_columns(
-        [left.data[n] for n in left.data] + [start, lo, hi], li_c,
-        use_kernel)
-    startg, log, hig = lgather[-3:]
-    within = j - startg
-    has_match = (hig - log) > 0
-    ridx_pos = jnp.clip(log + within, 0, cap_r - 1)
-    (ridx,) = _gather_columns([order_r], ridx_pos, use_kernel)
+    with jax.named_scope("search"):
+        if use_kernel:
+            from repro.kernels import ops as kops
+            lo, hi = kops.merge_positions(srk, lkey)
+        else:
+            lo = jnp.searchsorted(srk, lkey, side="left")
+            hi = jnp.searchsorted(srk, lkey, side="right")
+    # the output's segments: each probe row's run of matches
+    with jax.named_scope("segment"):
+        cnt = jnp.where(left.valid, hi - lo, 0)
+        if how == "left_outer":
+            cnt = jnp.where(left.valid & (cnt == 0), 1, cnt)
+        offs = jnp.cumsum(cnt)                      # inclusive
+        start = offs - cnt
+        total = offs[-1]
+        j = jnp.arange(out_capacity)
+        if use_kernel:
+            from repro.kernels import ops as kops
+            _, li = kops.merge_positions(offs, j)
+        else:
+            li = jnp.searchsorted(offs, j, side="right")
+    with jax.named_scope("gather"):
+        li_c = jnp.clip(li, 0, left.capacity - 1)
+        lgather = _gather_columns(
+            [left.data[n] for n in left.data] + [start, lo, hi], li_c,
+            use_kernel)
+        startg, log, hig = lgather[-3:]
+        within = j - startg
+        has_match = (hig - log) > 0
+        ridx_pos = jnp.clip(log + within, 0, cap_r - 1)
+        (ridx,) = _gather_columns([order_r], ridx_pos, use_kernel)
+        rnames = [n for n in right.data
+                  if not (right_prefix + n in left.data
+                          and n in right_on)]
+        rgather = _gather_columns([right.data[n] for n in rnames], ridx,
+                                  use_kernel)
     out_valid = j < total
 
     data = {n: g for n, g in zip(left.data, lgather)}
-    rnames = [n for n in right.data
-              if not (right_prefix + n in data and n in right_on)]
-    rgather = _gather_columns([right.data[n] for n in rnames], ridx,
-                              use_kernel)
     for n, g in zip(rnames, rgather):
         out_name = right_prefix + n
         if out_name in data:
@@ -561,8 +574,9 @@ def nest_level(bag: FlatBag, group_cols: Sequence[str],
     cap = bag.capacity
     group_cols = tuple(group_cols)
     sbag, seg_id = _segments(bag, group_cols)
-    exists, parent_valid, firsts, _ = _segment_firsts(
-        sbag, seg_id, group_cols, use_kernel)
+    with jax.named_scope("segment"):
+        exists, parent_valid, firsts, _ = _segment_firsts(
+            sbag, seg_id, group_cols, use_kernel)
 
     pdata = dict(firsts)
     pdata[label_col] = jnp.arange(cap, dtype=jnp.int64)

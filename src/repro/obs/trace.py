@@ -23,6 +23,11 @@ Design constraints (the zero-retrace contract):
 * **No traced values in attributes.** Call sites pass only static
   Python values (names, key tuples, sites); a jax tracer stored in an
   attr would leak out of the trace.
+* **On the profiler's clock.** An enabled span also enters a
+  ``jax.profiler.TraceAnnotation`` of the same name (carrying its
+  scalar attributes), so under a ``jax.profiler`` session every engine
+  span sits on the host plane beside the device ops, nested as in
+  ``Span.tree()``. The disabled path creates no annotation.
 """
 
 from __future__ import annotations
@@ -31,6 +36,8 @@ import json
 import time
 from contextlib import contextmanager
 from typing import List, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class Span:
@@ -149,19 +156,25 @@ TRACER = Tracer()
 
 
 class _SpanCtx:
-    __slots__ = ("_name", "_attrs", "_span")
+    __slots__ = ("_name", "_attrs", "_span", "_mark")
 
     def __init__(self, name: str, attrs: dict):
         self._name = name
         self._attrs = attrs
         self._span = None
+        self._mark = None
 
     def __enter__(self) -> Span:
+        self._mark = TraceAnnotation(self._name, **{
+            k: v for k, v in self._attrs.items()
+            if isinstance(v, (str, int, float))})
+        self._mark.__enter__()
         self._span = TRACER.push(self._name, self._attrs)
         return self._span
 
     def __exit__(self, *exc) -> bool:
         TRACER.pop(self._span)
+        self._mark.__exit__(*exc)
         return False
 
 

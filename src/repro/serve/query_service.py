@@ -61,7 +61,7 @@ from repro.core import nrc as N
 from repro.core.plans import ExecSettings
 from repro.core.unnesting import Catalog
 from repro.errors import CapacityOverflowError
-from repro.obs.trace import span as _span
+from repro.obs.trace import Span, span as _span
 
 
 def lift_program(program: N.Program) -> Tuple[N.Program, list]:
@@ -75,6 +75,15 @@ def lift_program(program: N.Program) -> Tuple[N.Program, list]:
         assigns.append(N.Assignment(a.name, e, a.role, a.path,
                                     a.parent, a.label_attr))
     return N.Program(assigns), vals
+
+
+def _note_answer(sp, outputs: Dict[str, FlatBag]) -> None:
+    """``answer_bytes`` on an enabled ``query.execute`` span: the
+    summed ``nbytes`` of the answer's data and valid arrays, global over
+    shards (shapes only: no wait on the device)."""
+    if isinstance(sp, Span):
+        sp.attrs["answer_bytes"] = sum(
+            a.nbytes for a in jax.tree_util.tree_leaves(outputs))
 
 
 def _class_capacity(n: int) -> int:
@@ -394,8 +403,10 @@ class QueryService:
             "StoredDataset itself (execute / execute_stored), or run "
             "the eager path via codegen.run_flat_program")
         with _span("query.execute",
-                   path="dist" if self.mesh is not None else "local"):
-            return self._execute(program, env, skew_hints)
+                   path="dist" if self.mesh is not None else "local") as sp:
+            out = self._execute(program, env, skew_hints)
+            _note_answer(sp, out)
+            return out
 
     def _execute(self, program: N.Program, env,
                  skew_hints: Optional[dict]) -> Dict[str, FlatBag]:
@@ -426,7 +437,8 @@ class QueryService:
                         f"grow the set, or re-warm the entry for the "
                         f"new one")
             return out
-        return entry.exe(env_c, params)
+        with _span("query.dispatch"):
+            return entry.exe(env_c, params)
 
     def execute_many(self, programs: Sequence[N.Program],
                      env: Dict[str, FlatBag]) -> List[Dict[str, FlatBag]]:
@@ -572,11 +584,15 @@ class QueryService:
         (the degraded re-scan after a chunk fault: capacities stay
         pinned, so the full scan reuses the warm executable);
         ``verify=True`` CRC-checks every loaded chunk."""
-        with _span("query.execute", path="stored", no_skip=no_skip):
+        with _span("query.execute", path="stored",
+                   no_skip=no_skip) as sp:
             entry, params, env = self._lookup_stored(
                 program, dataset, skew_hints,
                 no_skip=no_skip, verify=verify)
-            return entry.exe(env, params)
+            with _span("query.dispatch"):
+                out = entry.exe(env, params)
+            _note_answer(sp, out)
+            return out
 
     # -- morsel-streamed storage-backed execution --------------------------
     def _lookup_streaming(self, program: N.Program, dataset, root: str,
@@ -647,10 +663,12 @@ class QueryService:
         dataset's label columns are not monotone parent rids — fall
         back to ``execute_stored``."""
         with _span("query.execute", path="streaming",
-                   morsel_rows=morsel_rows):
-            return self._execute_stored_streaming(
+                   morsel_rows=morsel_rows) as sp:
+            out = self._execute_stored_streaming(
                 program, dataset, morsel_rows, root, skew_hints,
                 no_skip, verify)
+            _note_answer(sp, out)
+            return out
 
     def _execute_stored_streaming(self, program, dataset, morsel_rows,
                                   root, skew_hints, no_skip, verify
@@ -684,7 +702,8 @@ class QueryService:
                     req[part].columns, entry.class_caps[part],
                     pred=None if no_skip else req[part].pred,
                     params=params, verify=verify)
-            outs.append(entry.exe(env, params))
+            with _span("query.dispatch", morsel=k):
+                outs.append(entry.exe(env, params))
         return _fold_streamed(folds, outs, self.settings)
 
     def unshred_stored(self, program: N.Program, dataset,

@@ -14,7 +14,6 @@ columns read/pruned, bytes read); the storage tests and
 from __future__ import annotations
 
 import os
-import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence
 
@@ -42,8 +41,8 @@ registry (``repro.obs``) under the ``storage.`` domain:
 ``columns_pruned`` (projection pushdown), ``parts_loaded``, and the
 byte ledger — ``bytes_read`` is bytes that actually came off disk
 (encoded chunks count their compressed blob, NOT the decoded rows),
-``bytes_decoded`` / ``chunks_decoded`` / ``decode_us`` meter the
-decode stage of encoded chunks."""
+``bytes_decoded`` / ``chunks_decoded`` meter the decode stage of
+encoded chunks (its time is the ``decode`` span's)."""
 
 DEVICE_DECODE = False
 """When True, encoded chunks decode through the Pallas kernels
@@ -61,6 +60,16 @@ def reset_storage_stats() -> None:
 
 def _count(name: str, n: int = 1) -> None:
     _METRICS.inc("storage." + name, n)
+
+
+def _to_device(host: np.ndarray, part: str, col: str) -> jax.Array:
+    """One column's host-to-device copy, in a ``storage.to_device``
+    span (``rows``, ``bytes``: what is handed to the device).
+    ``device_put`` skips ``jnp.asarray``'s trace/convert layer — on the
+    scan path this is a pure host->device copy."""
+    with _span("storage.to_device", part=part, col=col,
+               rows=host.shape[0], bytes=host.nbytes):
+        return jax.device_put(host)
 
 
 def _decode_device(enc: dict, m: Dict[str, np.ndarray]) -> np.ndarray:
@@ -242,7 +251,6 @@ class StoredPart:
         if enc is not None:
             with _span("decode", part=meta.name, col=col, chunk=i,
                        codec=enc.get("codec")):
-                t0 = time.perf_counter()
                 # a blob that does not parse is a fault of the data; a
                 # kernel that fails to compile or run is not, and raises
                 # as itself
@@ -261,8 +269,6 @@ class StoredPart:
                 if DEVICE_DECODE:
                     a = _decode_device(enc, members)
                 if count:
-                    _count("decode_us",
-                           int((time.perf_counter() - t0) * 1e6))
                     _count("bytes_decoded", int(a.nbytes))
                     _count("chunks_decoded")
         if rule is not None and rule.kind == "corrupt" and a.size:
@@ -332,10 +338,8 @@ class StoredPart:
                 buf[off:off + a.shape[0]] = a
                 off += a.shape[0]
             buf[off:] = dtype.type(0) if dtype.kind != "b" else False
-            # device_put skips jnp.asarray's trace/convert layer — on
-            # the scan path this is a pure host->device copy
-            data[col] = jax.device_put(buf)
-        valid = jax.device_put(np.arange(cap) < nrows)
+            data[col] = _to_device(buf, meta.name, col)
+        valid = _to_device(np.arange(cap) < nrows, meta.name, "valid")
         props = self._props(cols)
         return FlatBag(data, valid, props)
 

@@ -6,11 +6,15 @@ turning the tracer ON must not change a single output bit and must not
 cost a single extra retrace (spans inside jitted code are host-side and
 fire at trace time only)."""
 
+import contextlib
+import glob
 import json
+import re
 
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import codegen as CG
@@ -21,6 +25,8 @@ from repro.obs import (REGISTRY, TRACER, MetricsRegistry, StatsFeedback,
                        explain_analyze, metrics_scope,
                        record_observed_stats, span, tracing)
 from repro.serve.query_service import QueryService
+from repro.serve.runtime import QueryRequest, ServingRuntime
+from repro.storage import StorageCatalog
 
 from helpers import (INPUT_TYPES, gen_cop, gen_parts,
                      running_example_query)
@@ -30,10 +36,13 @@ def _program():
     return N.Program([N.Assignment("Q", running_example_query())])
 
 
+def _data():
+    return {"Part": gen_parts(n=20, seed=0),
+            "COP": gen_cop(6, 3, 4, 20, seed=1)}
+
+
 def _env():
-    return CG.columnar_shred_inputs(
-        {"Part": gen_parts(n=20, seed=0),
-         "COP": gen_cop(6, 3, 4, 20, seed=1)}, INPUT_TYPES)
+    return CG.columnar_shred_inputs(_data(), INPUT_TYPES)
 
 
 # ---------------------------------------------------------------------------
@@ -169,9 +178,33 @@ def test_unbalanced_exception_unwinds_spans():
 # differential: telemetry must not change results or cost retraces
 # ---------------------------------------------------------------------------
 
-def test_tracing_is_bit_identical_and_zero_retrace():
+def _compiled_text(svc, env) -> str:
+    entry, params, env_c = svc._lookup(_program(), env, None)
+    return entry.exe._fn.lower(env_c, entry.exe.bind(params)) \
+        .compile().as_text()
+
+
+def _instructions(hlo: str) -> list:
+    """The compiled module's computations, instruction by instruction,
+    without their metadata (op names, source frames)."""
+    return [re.sub(r", metadata=\{[^}]*\}", "", line)
+            for line in hlo.splitlines()
+            if line.lstrip().startswith(("%", "ROOT ", "ENTRY "))]
+
+
+def _assert_same_bits(base, out):
+    for k in base:
+        assert np.array_equal(np.asarray(base[k].valid),
+                              np.asarray(out[k].valid))
+        for c in base[k].columns:
+            assert np.array_equal(np.asarray(base[k].col(c)),
+                                  np.asarray(out[k].col(c)))
+
+
+def test_tracing_is_bit_identical_and_zero_retrace(monkeypatch):
     svc = QueryService(INPUT_TYPES)
     env = _env()
+    t0 = CG.TRACE_STATS.get("traces", 0)
     base = svc.execute(_program(), env)
     t_cold = CG.TRACE_STATS.get("traces", 0)
     warm_off = svc.execute(_program(), env)
@@ -180,17 +213,79 @@ def test_tracing_is_bit_identical_and_zero_retrace():
     with tracing(reset=True):
         warm_on = svc.execute(_program(), env)
         names = TRACER.span_names()
+        (ex,) = TRACER.find("query.execute")
     # enabling the tracer on a WARM family: no retrace, same bits
     assert CG.TRACE_STATS.get("traces", 0) == t_cold
-    assert "query.execute" in names
+    assert "query.execute" in names and "query.dispatch" in names
     assert "compile" not in names           # warm: nothing compiled
+    assert ex.attrs["answer_bytes"] == sum(
+        a.nbytes for a in jax.tree_util.tree_leaves(warm_on))
     for out in (warm_off, warm_on):
-        for k in base:
-            assert np.array_equal(np.asarray(base[k].valid),
-                                  np.asarray(out[k].valid))
-            for c in base[k].columns:
-                assert np.array_equal(np.asarray(base[k].col(c)),
-                                      np.asarray(out[k].col(c)))
+        _assert_same_bits(base, out)
+
+    # the plan-operator scopes are metadata only: the family traced
+    # with every named scope a no-op compiles to the same instructions,
+    # gives the same bits and costs the same number of traces
+    scoped = _compiled_text(svc, env)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare_svc = QueryService(INPUT_TYPES)
+    t1 = CG.TRACE_STATS.get("traces", 0)
+    bare = bare_svc.execute(_program(), env)
+    assert CG.TRACE_STATS.get("traces", 0) - t1 == t_cold - t0
+    _assert_same_bits(base, bare)
+    plain = _compiled_text(bare_svc, env)
+    assert "SumAggP" in scoped and "SumAggP" not in plain
+    assert _instructions(scoped) == _instructions(plain)
+
+
+_MIRRORED = ("serve.submit", "query.execute", "storage.load_part",
+             "storage.to_device", "query.dispatch")
+
+
+def _profiled_submit(rt, req, trace_dir, enabled: bool) -> list:
+    """(name, start, end) of the engine's host events that a profiler
+    session around one served request records."""
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        with tracing(enabled, reset=True):
+            assert rt.submit(req).ok
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(trace_dir / "**" / "*.xplane.pb"),
+                        recursive=True)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if not plane.name.startswith("/device")
+            for line in plane.lines for e in line.events
+            if e.name in _MIRRORED]
+
+
+def test_spans_mirror_on_the_profiler_clock(tmp_path):
+    ds = StorageCatalog(str(tmp_path / "store")).write(
+        "d", _data(), INPUT_TYPES, chunk_rows=8)
+    rt = ServingRuntime(QueryService(INPUT_TYPES))
+    req = QueryRequest(_program(), ds)
+    assert rt.submit(req).ok                # compiled outside the trace
+    events = _profiled_submit(rt, req, tmp_path / "on", True)
+    first = {}
+    for name, a, b in events:
+        first.setdefault(name, (a, b))
+    assert set(first) == set(_MIRRORED)
+
+    def inside(inner, outer):
+        return first[outer][0] <= first[inner][0] \
+            and first[inner][1] <= first[outer][1]
+    assert inside("query.execute", "serve.submit")
+    assert inside("storage.load_part", "query.execute")
+    assert inside("storage.to_device", "storage.load_part")
+    assert inside("query.dispatch", "query.execute")
+    # the tracer's own tree agrees, and the copy's size is recorded
+    (sp,) = [s for s in TRACER.find("storage.to_device")
+             if s.attrs["col"] == "valid"][:1]
+    assert sp.attrs["bytes"] == sp.attrs["rows"] > 0
+    assert _profiled_submit(rt, req, tmp_path / "off", False) == []
 
 
 def test_cold_compile_emits_compile_spans():
