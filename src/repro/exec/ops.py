@@ -401,6 +401,45 @@ def _gather_columns(arrs: List[jnp.ndarray], idx: jnp.ndarray,
     return [_from_i64_bits(out[:, i], a.dtype) for i, a in enumerate(arrs)]
 
 
+SORT_ROW_GATHERS = 0.2
+"""What one row of a sort costs, in rows gathered by a binary search's
+step: the largest ratio of the two per-row costs over the served
+cells' probe shapes on a TPU v5e, 0.19, rounded up
+(``python -m benchmarks.join_probe``)."""
+
+
+def _merge_rank_left(sorted_keys: jnp.ndarray,
+                     queries: jnp.ndarray) -> jnp.ndarray:
+    """``jnp.searchsorted(sorted_keys, queries, side="left")`` by one
+    co-sort, bit for bit: no gather and no scatter. The queries go
+    first in the concatenation, so the source index, the second sort
+    key, puts each query before an equal build key; an exclusive
+    cumsum of the build rows then counts the keys strictly below each
+    query, and a sort on the source index puts the counts back in query
+    order."""
+    n = queries.shape[0]
+    keys = jnp.concatenate([queries, sorted_keys])
+    src = jnp.arange(keys.shape[0], dtype=jnp.int32)
+    _, src = jax.lax.sort((keys, src), num_keys=2)
+    build = (src >= n).astype(jnp.int32)
+    below = jnp.cumsum(build, dtype=jnp.int32) - build
+    _, below = jax.lax.sort((src, below), num_keys=1)
+    return below[:n]
+
+
+def _probe_left(sorted_keys: jnp.ndarray,
+                queries: jnp.ndarray) -> jnp.ndarray:
+    """Left insertion points of ``queries`` in ``sorted_keys``, by the
+    cheaper of the two methods at these static sizes: a binary search
+    gathers ``n · bit_length(r)`` rows one dependent step at a time,
+    the co-sort sorts ``n + r`` rows twice."""
+    n, r = queries.shape[0], sorted_keys.shape[0]
+    if 2 * (n + r) * SORT_ROW_GATHERS < n * r.bit_length():
+        _count("merge_probe")
+        return _merge_rank_left(sorted_keys, queries)
+    return jnp.searchsorted(sorted_keys, queries)
+
+
 def fk_join(left: FlatBag, right: FlatBag, left_on: Sequence[str],
             right_on: Sequence[str], how: str = "inner",
             right_prefix: str = "", use_kernel: bool = False) -> FlatBag:
@@ -422,7 +461,7 @@ def fk_join(left: FlatBag, right: FlatBag, left_on: Sequence[str],
             from repro.kernels import ops as kops
             pos, _ = kops.merge_positions(srk, lkey)
         else:
-            pos = jnp.searchsorted(srk, lkey)
+            pos = _probe_left(srk, lkey)
     rnames = [n for n in right.data
               if not (right_prefix + n in left.data and n in right_on)]
     with jax.named_scope("gather"):

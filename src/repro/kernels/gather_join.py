@@ -1,7 +1,9 @@
 """Pallas TPU kernels for the join hot loop (fk_join / general_join).
 
-The jnp join path issues a double ``searchsorted`` plus several random
-gathers — scalar-unit work on TPU. These kernels turn both into blocked
+On the jnp path, ``fk_join`` ranks its probes by one co-sort
+(``exec.ops._merge_rank_left``) and ``general_join`` by a double
+``searchsorted``; both then make several random gathers, scalar-unit
+work on TPU. These kernels turn positions and gathers into blocked
 vector/matrix work:
 
 * ``merge_positions_pallas`` — the sorted-merge position computation:

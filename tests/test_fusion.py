@@ -129,6 +129,42 @@ def test_sorted_build_side_skips_argsort():
     assert X.SORT_STATS.get("build_sort_skipped", 0) == 1
 
 
+def test_fk_join_probes_by_co_sort_without_a_loop():
+    """At 2^12 probe rows against 2^10 build rows the default path ranks
+    by the co-sort: no ``while`` (binary search) in the compiled
+    program, one ``merge_probe`` per join, and the joins' answers."""
+    import jax
+    rng = np.random.default_rng(7)
+    n, r = 2**12, 2**10
+    lk = jnp.asarray(rng.integers(0, 2 * r, n))
+    lv = jnp.asarray(rng.integers(0, 100, n))
+    rk = jnp.asarray(rng.permutation(2 * r)[:r])   # unique, unsorted
+    rv = jnp.asarray(rng.integers(0, 100, r))
+    valid_r = jnp.asarray(rng.random(r) < 0.9)
+
+    def two_joins(lk, lv, rk, rv, valid_r):
+        left = FlatBag({"k": lk, "v": lv}, jnp.ones(n, bool))
+        a = FlatBag({"k": rk, "a": rv}, valid_r)
+        b = FlatBag({"k": rk, "b": rv * 2}, jnp.ones(r, bool))
+        j = X.fk_join(X.fk_join(left, a, ("k",), ("k",)), b, ("k",), ("k",))
+        return j.col("a"), j.col("b"), j.valid
+
+    fn = jax.jit(two_joins)
+    X.reset_sort_stats()
+    a, b, valid = fn(lk, lv, rk, rv, valid_r)
+    assert X.SORT_STATS.get("merge_probe", 0) == 2
+    hlo = fn.lower(lk, lv, rk, rv, valid_r).compile().as_text()
+    assert " while(" not in hlo
+    row = {int(k): i for i, k in enumerate(np.asarray(rk))}
+    want = np.array([k in row and bool(valid_r[row[k]])
+                     for k in np.asarray(lk).tolist()])
+    np.testing.assert_array_equal(np.asarray(valid), want)
+    idx = [row[k] for k in np.asarray(lk)[want].tolist()]
+    np.testing.assert_array_equal(np.asarray(a)[want], np.asarray(rv)[idx])
+    np.testing.assert_array_equal(np.asarray(b)[want],
+                                  2 * np.asarray(rv)[idx])
+
+
 def test_general_join_preserves_probe_order():
     left = X.sum_by(_mk_left()[0], ("g", "k"), ("v",))
     right = _mk_right()

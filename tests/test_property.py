@@ -6,6 +6,7 @@ equals direct NRC evaluation; value shredding round-trips; columnar ops
 match their Python semantics."""
 
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 import jax.numpy as jnp
@@ -108,6 +109,57 @@ def test_fk_join_matches_python(rows, n_right):
                   for r in rows if r["k"] < n_right)
     got = sorted((r["k"], r["v"], r["w"]) for r in out)
     assert got == want
+
+
+I64 = np.iinfo(np.int64)
+
+
+def _probe_case(kind: str, n: int, r: int, seed: int):
+    """(sorted build keys, probe keys) for one case of the probe test."""
+    rng = np.random.default_rng(seed)
+    if kind == "dup":
+        build = rng.integers(-4, 4, r)
+        probe = rng.integers(-5, 5, n)
+    else:
+        build = rng.integers(I64.min, I64.max, r, dtype=np.int64)
+        probe = rng.integers(I64.min, I64.max, n, dtype=np.int64)
+    if kind == "padded":
+        # _build_side's invalid rows: I64_MAX after the valid keys
+        build[r // 2:] = I64.max
+    if kind == "extremes":
+        build[:2] = I64.min
+        build[-2:] = I64.max
+        probe[:4] = [I64.min, I64.max, I64.min, I64.max]
+    if r and kind != "extremes":
+        # probes that hit build keys exactly
+        probe[: n // 2] = rng.choice(build, n // 2)
+    return (jnp.asarray(np.sort(build).astype(np.int64)),
+            jnp.asarray(probe.astype(np.int64)))
+
+
+@pytest.mark.parametrize("kind,n,r,merged", [
+    ("empty_build", 9, 0, False),
+    ("empty_probe", 0, 10, False),
+    ("one_row_build", 7, 1, True),
+    ("dup", 50, 40, True),
+    ("padded", 100, 64, True),
+    ("extremes", 33, 12, True),
+    ("non_pow2", 1000, 333, True),
+    ("probe_smaller", 5, 1000, False),
+    ("probe_larger", 4096, 17, True),
+])
+def test_probe_rank_equals_searchsorted(kind, n, r, merged):
+    """The fk probe equals ``searchsorted(side="left")`` bit for bit on
+    both sides of its size rule, and so does the co-sort alone."""
+    build, probe = _probe_case(kind, n, r, seed=n * 1009 + r)
+    want = np.asarray(jnp.searchsorted(build, probe, side="left"))
+    got = X._probe_left(build, probe)
+    assert X.SORT_STATS.get("merge_probe", 0) == int(merged)
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got), want)
+    rank = X._merge_rank_left(build, probe)
+    assert rank.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(rank), want)
 
 
 @settings(max_examples=20, deadline=None)
