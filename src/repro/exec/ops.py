@@ -8,7 +8,7 @@ operators (Fig. 10) under the TPU static-shape discipline:
   join       -> fk_join           (build side unique — every benchmark join)
                 general_join      (M:N, static output capacity + overflow)
   outer-join -> fk_join(how="left_outer")
-  Gamma+     -> sum_by            (sort + segment-sum; Pallas kernel inside)
+  Gamma+     -> sum_by            (sort + segmented scan + compaction sort)
   Gamma_u    -> nest_level        (CSR regroup; labels = dense group ids)
   dedup      -> dedup
   mu / mu-bar-> flatten_child / outer_unnest (wide flattening, standard route)
@@ -133,42 +133,60 @@ def _key_arrays(bag: FlatBag, cols: Sequence[str]) -> List[jnp.ndarray]:
 # ---------------------------------------------------------------------------
 
 def _lexsort(bag: FlatBag, cols: Tuple[str, ...]) -> FlatBag:
-    """Sort rows by (invalid-last, cols lexicographic). The result
-    delivers ``sorted_by = cols`` with ``invalid_last``."""
+    """Sort rows by (invalid-last, cols lexicographic), stably. One
+    ``lax.sort`` moves every column as an operand, so no row is
+    gathered by a permutation; the key columns come back from their
+    sorted int64 views. The result delivers ``sorted_by = cols`` with
+    ``invalid_last``."""
     _count("lexsort")
     with jax.named_scope("sort"):
         keys = _key_arrays(bag, cols)
-        order = jnp.lexsort(tuple(reversed(keys)) + (~bag.valid,))
-        data = {n: a[order] for n, a in bag.data.items()}
-        valid = bag.valid[order]
+        rest = [n for n in bag.data if n not in cols]
+        out = jax.lax.sort(
+            (~bag.valid, *keys, *[bag.data[n] for n in rest]),
+            num_keys=1 + len(keys), is_stable=True)
+        valid = ~out[0]
+        moved = dict(zip(rest, out[1 + len(keys):]))
+        moved.update((c, _from_i64_bits(k, bag.col(c).dtype))
+                     for c, k in zip(cols, out[1:1 + len(keys)]))
+        data = {n: moved[n] for n in bag.data}
     props = PhysicalProps(sorted_by=cols, invalid_last=True,
                           partitioning=_part_if(bag, bag.data)) \
         if ORDER_AWARE else None
     return FlatBag(data, valid, props)
 
 
-def _presorted_seg_ids(bag: FlatBag, cols: Tuple[str, ...]) -> jnp.ndarray:
+def _presorted_seg_ids(bag: FlatBag, cols: Tuple[str, ...],
+                       invalid_last: bool) -> jnp.ndarray:
     """Dense group ids for a bag whose VALID rows are already clustered
-    by ``cols``. Invalid rows may be interleaved: a valid row starts a
-    new segment iff any key column differs from the previous *valid*
-    row; invalid rows fold into the running segment (their values are
-    masked out by every consumer)."""
+    by ``cols``. A valid row starts a new segment iff any key column
+    differs from the previous *valid* row; invalid rows fold into the
+    running segment (their values are masked out by every consumer).
+    With ``invalid_last`` the previous valid row of a valid row is the
+    row before it, so each key column is compared with its shift by
+    one; interleaved invalid rows need the gather of the last valid
+    row."""
     cap = bag.capacity
-    idx = jnp.arange(cap)
-    last_valid = jax.lax.cummax(jnp.where(bag.valid, idx, -1))
-    prev_valid = jnp.concatenate(
-        [jnp.full((1,), -1, last_valid.dtype), last_valid[:-1]])
-    has_prev = prev_valid >= 0
-    pv = jnp.clip(prev_valid, 0, cap - 1)
-    differs = jnp.zeros(cap, bool)
-    for c in cols:
-        # compare the SAME int64 bit-view _lexsort orders by: raw float
-        # comparison would split bit-identical NaNs (NaN != NaN) and
-        # merge bit-distinct +0.0/-0.0 that the sort left non-adjacent
-        a = _to_i64_bits(bag.col(c))
-        differs = differs | (a != a[pv])
-    seg_start = bag.valid & (~has_prev | differs)
-    seg_start = seg_start.at[0].set(True)
+    # compare the SAME int64 bit-view _lexsort orders by: raw float
+    # comparison would split bit-identical NaNs (NaN != NaN) and merge
+    # bit-distinct +0.0/-0.0 that the sort left non-adjacent
+    keys = [_to_i64_bits(bag.col(c)) for c in cols]
+    if invalid_last:
+        _count("seg_shift")
+        differs = jnp.zeros(cap - 1, bool)
+        for a in keys:
+            differs = differs | (a[1:] != a[:-1])
+        seg_start = bag.valid[1:] & differs
+    else:
+        idx = jnp.arange(cap)
+        last_valid = jax.lax.cummax(jnp.where(bag.valid, idx, -1))
+        prev_valid = last_valid[:-1]
+        pv = jnp.clip(prev_valid, 0, cap - 1)
+        differs = jnp.zeros(cap - 1, bool)
+        for a in keys:
+            differs = differs | (a[1:] != a[pv])
+        seg_start = bag.valid[1:] & ((prev_valid < 0) | differs)
+    seg_start = jnp.concatenate([jnp.ones(1, bool), seg_start])
     return jnp.cumsum(seg_start.astype(jnp.int32)) - 1
 
 
@@ -189,7 +207,8 @@ def _segments(bag: FlatBag, key_cols: Sequence[str]
     else:
         sbag = _lexsort(bag, cols)
     with jax.named_scope("segment"):
-        seg_id = _presorted_seg_ids(sbag, cols)
+        seg_id = _presorted_seg_ids(
+            sbag, cols, sbag is not bag or bag.props.invalid_last)
     if ORDER_AWARE and _cache_ok(sbag, seg_id):
         sbag.props.seg_cache[cols] = seg_id
     return sbag, seg_id
@@ -200,11 +219,17 @@ def _segment_firsts(sbag: FlatBag, seg_id: jnp.ndarray, gather_cols,
                     ) -> Tuple[jnp.ndarray, jnp.ndarray, Dict[str, jnp.ndarray],
                                Dict[str, jnp.ndarray]]:
     """Shared Gamma tail: per segment, (exists, first-row validity,
-    first-row values of ``gather_cols``, summed ``val_cols``).
+    first-row values of ``gather_cols``, summed ``val_cols``). Row
+    ``s`` holds segment ``s``; rows at or past the segment count are
+    zero.
+
+    The jnp path moves no row by scatter or gather: a reverse segmented
+    scan leaves each segment's sums on its start row, and one sort keyed
+    by the start rows' segment ids compacts them to the front (DESIGN.md
+    "Grouping without scatter").
 
     With ``use_kernel`` this is ONE fused Pallas pass (segment-sum +
-    first-row gather) instead of segment_min + separate gathers +
-    per-column segment_sum. The kernel accumulates in f32 (the MXU
+    first-row gather). The kernel accumulates in f32 (the MXU
     discipline, DESIGN.md), which would silently truncate integer
     sums past 2^24 — so integer value columns keep the exact jnp
     segment_sum path."""
@@ -233,16 +258,61 @@ def _segment_firsts(sbag: FlatBag, seg_id: jnp.ndarray, gather_cols,
                     jnp.where(sbag.valid, sbag.col(v), 0), seg_id,
                     num_segments=cap)
         return exists, first_valid, firsts, summed
-    idx = jnp.arange(cap)
-    first = jax.ops.segment_min(idx, seg_id, num_segments=cap)
-    first_c = jnp.clip(first, 0, cap - 1)
-    exists = first < cap
-    first_valid = exists & sbag.valid[first_c]
-    firsts = {c: sbag.col(c)[first_c] for c in gather_cols}
-    summed = {v: jax.ops.segment_sum(
-        jnp.where(sbag.valid, sbag.col(v), 0), seg_id, num_segments=cap)
-        for v in val_cols}
+    _count("segment_scan")
+    # seg_id is dense and nondecreasing: a segment starts where it steps
+    start = jnp.concatenate([jnp.ones(1, bool), seg_id[1:] != seg_id[:-1]])
+    ends = jnp.concatenate([start[1:], jnp.ones(1, bool)])
+    sums = _segment_suffix_sums(
+        [jnp.where(sbag.valid, sbag.col(v), 0) for v in val_cols], ends)
+    # every row that starts no segment keys ``cap`` and sorts last
+    out = jax.lax.sort(
+        (jnp.where(start, seg_id, cap).astype(jnp.int32), sbag.valid,
+         *[sbag.col(c) for c in gather_cols], *sums), num_keys=1)
+    exists = jnp.arange(cap) < seg_id[-1] + 1
+    first_valid = exists & out[1]
+
+    def keep(a):
+        # rows past the segment count are zero; so is a sum of -0.0s,
+        # as the sum of a zero-initialised accumulation would be
+        return jnp.where(exists & (a != 0), a, jnp.zeros_like(a))
+
+    firsts = {c: jnp.where(exists, a, jnp.zeros_like(a))
+              for c, a in zip(gather_cols, out[2:2 + len(gather_cols)])}
+    summed = {v: keep(a)
+              for v, a in zip(val_cols, out[2 + len(gather_cols):])}
     return exists, first_valid, firsts, summed
+
+
+def _segment_suffix_sums(vals: List[jnp.ndarray], ends: jnp.ndarray
+                         ) -> List[jnp.ndarray]:
+    """Per row, the sum of its segment's values from that row to the
+    segment's last row (``ends`` marks last rows), so a segment's start
+    row holds the segment's sum. A reverse segmented scan: a float sum
+    depends only on its own segment's rows, where a difference of
+    prefix sums would carry the rounding of every earlier segment.
+
+    Step ``k`` adds to each row the partial sum ``2^k`` rows on unless
+    its span already holds a segment end: ``bit_length(n - 1)`` passes
+    over the rows, each a contiguous shift. A loop, so the compiler
+    sees one pass and not a chain of them."""
+    if not vals:
+        return []
+    n = ends.shape[0]
+
+    def ahead(a, d, fill):
+        # a[i + d], and ``fill`` past the last row
+        pad = jnp.concatenate([a, jnp.full((n,), fill, a.dtype)])
+        return jax.lax.dynamic_slice(pad, (d,), (n,))
+
+    def step(k, carry):
+        *vs, done = carry
+        d = jnp.left_shift(1, k)
+        vs = [jnp.where(done, v, v + ahead(v, d, 0)) for v in vs]
+        return (*vs, done | ahead(done, d, True))
+
+    *sums, _ = jax.lax.fori_loop(0, (n - 1).bit_length(), step,
+                                 (*vals, ends))
+    return sums
 
 
 # ---------------------------------------------------------------------------

@@ -312,3 +312,26 @@ def test_dist_join_reuses_shipped_keys():
     got = sorted((r["k"], r["v"], r["w"]) for r in out["out"].to_rows())
     want = sorted((r["k"], r["v"], float(r["k"] * 10)) for r in rows)
     assert got == want
+
+
+def test_served_revenue_request_groups_by_shift_scan_and_sort(tmp_path):
+    """One request of the served revenue family (query (b) over stored
+    TPC-H) groups by the shifted compare, the segmented scan and the
+    compaction sort, and its compiled program holds no scatter."""
+    import chip_smoke as S
+    from benchmarks.common import CATALOG
+    from repro.data.generators import TPCH_TYPES, gen_tpch
+    from repro.serve import QueryService
+    types = {k: TPCH_TYPES[k] for k in ("Lineitem", "Orders", "Part")}
+    ds = S.write_dataset(str(tmp_path), "t", gen_tpch(40, 0.0, 0), types,
+                         64)
+    svc = QueryService(types, catalog=CATALOG)
+    X.reset_sort_stats()
+    svc.execute_stored(S.query_b(50.0), ds)
+    assert X.SORT_STATS.get("segment_scan", 0) >= 1, X.SORT_STATS
+    assert X.SORT_STATS.get("seg_shift", 0) >= 1, X.SORT_STATS
+    (entry,) = svc._cache.values()
+    env = ds.load_env(columns={p: r.columns
+                               for p, r in entry.storage_req.items()})
+    hlo = entry.exe._fn.lower(env, entry.exe.bind()).compile().as_text()
+    assert " scatter(" not in hlo

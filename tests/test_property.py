@@ -191,3 +191,115 @@ def test_nest_level_partitions_rows(rows):
     got = sorted((lbl_to_k[c["lbl"]], c["v"]) for c in crows)
     want = sorted((r["k"], r["v"]) for r in rows)
     assert got == want
+
+
+# -- grouping by sorts and scans, against the gathers and scatters ----------
+
+def _tail_case(case: str):
+    """(bag, invalid_last) for one case of the segment-tail test: rows
+    clustered by (k, kf) among the valid ones, int64 and integer-valued
+    float64 values."""
+    rng = np.random.default_rng(len(case))
+    n = {"one_row": 1, "non_pow2": 1000}.get(case, 64)
+    k = np.sort(rng.integers(0, 5, n))
+    if case == "one_segment":
+        k[:] = 3
+    if case == "own_segments":
+        k = np.arange(n)
+    kf = np.where(k % 2 == 0, 2.0, -1.0) * (k // 3)   # clustered with k
+    valid = np.ones(n, bool)
+    if case == "all_invalid":
+        valid[:] = False
+    if case == "leading_invalid":
+        valid[:5] = False
+    if case == "interleaved_invalid":
+        valid = rng.random(n) < 0.6
+    if case in ("trailing_invalid", "non_pow2", "own_segments"):
+        valid[n * 2 // 3:] = False
+    bag = FlatBag({"k": jnp.asarray(k), "kf": jnp.asarray(kf),
+                   "i": jnp.asarray(rng.integers(-99, 99, n)),
+                   "f": jnp.asarray(rng.integers(-99, 99, n)
+                                    .astype(np.float64))},
+                  jnp.asarray(valid))
+    invalid_last = not (~valid[:-1] & valid[1:]).any()
+    return bag, invalid_last
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view(np.int64) if a.dtype == np.float64 else a
+
+
+@pytest.mark.parametrize("case", [
+    "all_invalid", "one_row", "leading_invalid", "interleaved_invalid",
+    "trailing_invalid", "own_segments", "one_segment", "non_pow2"])
+def test_segment_tail_equals_scatter_formula(case):
+    """The scan-and-compaction tail equals the segment_min/segment_sum
+    formula it replaced bit for bit, on both segment-id paths; rows past
+    the segment count are zero."""
+    from benchmarks.segment_tail import scatter_tail
+    bag, invalid_last = _tail_case(case)
+    cols, vals = ("k", "kf"), ("i", "f")
+    seg = X._presorted_seg_ids(bag, cols, invalid_last)
+    np.testing.assert_array_equal(
+        np.asarray(seg), np.asarray(X._presorted_seg_ids(bag, cols, False)))
+    assert X.SORT_STATS.get("seg_shift", 0) == int(invalid_last)
+    exists, first_valid, firsts, summed = X._segment_firsts(
+        bag, seg, cols, False, vals)
+    assert X.SORT_STATS.get("segment_scan", 0) == 1
+    w_exists, w_first_valid, w_firsts, w_summed = scatter_tail(
+        bag, seg, cols, vals)
+    ex = np.asarray(w_exists)
+    np.testing.assert_array_equal(np.asarray(exists), ex)
+    np.testing.assert_array_equal(np.asarray(first_valid),
+                                  np.asarray(w_first_valid))
+    for got, want in ((firsts, w_firsts), (summed, w_summed)):
+        for c in want:
+            assert got[c].dtype == want[c].dtype
+            np.testing.assert_array_equal(_bits(got[c])[ex],
+                                          _bits(want[c])[ex])
+            np.testing.assert_array_equal(_bits(got[c])[~ex], 0)
+
+
+def _sort_case(case: str):
+    rng = np.random.default_rng(len(case) + 100)
+    n = 77
+    valid = rng.random(n) < 0.7
+    if case == "all_invalid":
+        valid[:] = False
+    key = rng.integers(0, 4, n)
+    kf = rng.integers(-2, 3, n).astype(np.float64)
+    if case == "float_bits":
+        special = np.array([0x0000000000000000, 0x8000000000000000,
+                            0x7FF8000000000000, 0x7FF8000000000001,
+                            0xFFF8000000000000, 0x7FF0000000000000],
+                           dtype=np.uint64).view(np.float64)
+        kf = rng.choice(special, n)
+    return FlatBag({"key": jnp.asarray(key), "kf": jnp.asarray(kf),
+                    "v": jnp.asarray(rng.integers(-9, 9, n)
+                                     .astype(np.float64)),
+                    "w": jnp.asarray(rng.integers(0, 9, n)
+                                     .astype(np.int32)),
+                    "b": jnp.asarray(rng.random(n) < 0.5)},
+                   jnp.asarray(valid))
+
+
+@pytest.mark.parametrize("case,cols", [
+    ("duplicates", ("key",)), ("duplicates", ("key", "kf")),
+    ("float_bits", ("kf",)), ("float_bits", ("kf", "key")),
+    ("all_invalid", ("key", "kf"))])
+def test_lexsort_carries_columns_like_argsort_and_gathers(case, cols):
+    """One sort that carries the columns equals a stable argsort by
+    (invalid-last, key bit-views) and a gather of every column, bit for
+    bit on every row, NaN payloads and signed zeros included."""
+    from benchmarks.segment_tail import gather_lexsort
+    bag = _sort_case(case)
+    got = X._lexsort(bag, cols)
+    want = gather_lexsort(bag, cols)
+    np.testing.assert_array_equal(np.asarray(got.valid),
+                                  np.asarray(want.valid))
+    assert list(got.data) == list(bag.data)
+    for c, a in want.data.items():
+        assert got.data[c].dtype == a.dtype
+        np.testing.assert_array_equal(_bits(got.data[c]), _bits(a))
+    assert got.props.invalid_last and got.props.sorted_by == cols
