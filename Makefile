@@ -18,13 +18,11 @@ test-slow:
 	$(PY) -m pytest -x -q -m slow
 
 # CI gate: both test tiers plus the quick benchmark smoke plus the
-# serving, storage and skew smokes. bench-quick includes the
-# distributed join->sum_by shuffle benchmark, which runs in its own
-# subprocess under --xla_force_host_platform_device_count=8 and asserts
-# the packed exchange's elision + correctness — shuffle regressions
-# fail here, not in production. serve-smoke asserts the plan-cache warm
-# path performs ZERO jax retracing (codegen.TRACE_STATS) and that
-# cross-assignment CSE evaluates a shared join subplan exactly once.
+# serving, storage and skew smokes. The packed exchange's elision and
+# correctness are gated by tests/test_dist.py in the slow tier.
+# serve-smoke asserts the plan-cache warm path performs ZERO jax
+# retracing (codegen.TRACE_STATS) and that cross-assignment CSE
+# evaluates a shared join subplan exactly once.
 # storage-smoke writes a dataset, reopens it, asserts query parity with
 # the in-memory path, >=1 zone-map chunk skipped on a selective N.Param
 # predicate, and zero warm retraces while chunk selection changes.

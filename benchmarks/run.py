@@ -89,18 +89,14 @@ def main() -> None:
 
     print("name,us_per_call,derived")
     sections = []
-    from benchmarks import (biomedical, fused_pipeline, representation,
-                            serving, storage, succinct, tpch_nested)
+    from benchmarks import (biomedical, representation, serving, storage,
+                            succinct, tpch_nested)
     sections.append(("tpch_nested (Fig.7)",
                      lambda: tpch_nested.run(scale=30 if args.quick else 60)))
     sections.append(("serving (plan-cache query service)",
                      lambda: serving.run(
                          n_orders=300 if args.quick else 2000,
                          invocations=20 if args.quick else 50)))
-    sections.append(("fused_pipeline (order-aware executor)",
-                     lambda: fused_pipeline.run(
-                         n=5000 if args.quick else 20000,
-                         dist_n=2000 if args.quick else 4000)))
     def storage_section():
         storage.run(n_orders=300 if args.quick else 2000,
                     n_parts=128 if args.quick else 512,

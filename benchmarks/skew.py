@@ -1,24 +1,18 @@
 """Skew-handling benchmark (paper Fig. 8 + App. E.5): nested-to-nested
 narrow query at level 2 over increasingly skewed data, SHRED vs
 SHRED_SKEW on 8 virtual devices — reporting runtime, shuffled rows,
-overflow (the TPU analogue of Spark's crashed runs), and — since the
-partitioning-aware shuffle — collective counts and exchange elisions
-for the packed single-collective path vs the legacy per-column path
-(the PR 1 baseline: one-hot scatter, one all_to_all per column, static
-16x buckets, no elision).
+collective counts and overflow (the TPU analogue of Spark's crashed
+runs).
 
-Since the compiler-integrated skew handling, a second scenario
-(``run_auto`` / ``--smoke``) exercises the AUTOMATIC pipeline end to
+``run_auto`` / ``--smoke`` exercises the automatic pipeline end to
 end: a skewed nested dataset is persisted through ``DatasetWriter``
 (streaming heavy-key sketch + zone maps), ``table_stats`` feeds the
-skew pass, and the same join->sum_by->nest query runs under three
-plans per Zipf point —
+skew pass, and the same join->sum_by->nest query runs under two plans
+per Zipf point —
 
-  * **auto**   — ``compile_program(skew_stats=...)``: SkewJoinP where
+  * **auto** — ``compile_program(skew_stats=...)``: SkewJoinP where
     the statistics predict imbalance, plain join otherwise;
-  * **off**    — skew pass disabled (forced-off baseline);
-  * **always** — runtime sampled skew on every join
-    (``skew_default=True``, the PR 2 behaviour).
+  * **off**  — compiled without statistics: the plain join.
 
 Reported per point: warm runtime, measured partition imbalance
 (max/mean receive load over the exchange sites), shuffled rows, and
@@ -40,80 +34,8 @@ import json
 import os
 import subprocess
 import sys
-import textwrap
 
 from .common import emit
-
-_CHILD = r"""
-import os
-# a CPU rehearsal on 8 virtual devices: never the accelerator the
-# parent process may hold
-os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import sys, json, time
-sys.path.insert(0, r"%(src)s")
-sys.path.insert(0, r"%(bench)s")
-import jax
-import repro
-from repro.core import codegen as CG
-from repro.core import interpreter as I
-from repro.core import materialization as M
-from repro.core import nrc as N
-from repro.core.plans import ExecSettings
-from repro.data.generators import TPCH_TYPES, gen_tpch
-from repro.exec.dist import device_mesh_1d, compile_distributed
-from benchmarks.common import CATALOG, materialize_nested_input, \
-    nested_to_nested_query
-
-MODES = (("legacy", dict(shuffle_mode="legacy", cap_factor=16.0)),
-         ("packed", dict(shuffle_mode="packed", cap_factor=2.0,
-                         adaptive=True)))
-WARM_ITERS = 5
-
-out = []
-for skew in (0.0, 0.8, 1.2, 2.0):
-    db = gen_tpch(scale=48, skew=skew, seed=0)
-    nested, nty = materialize_nested_input(db, 2)
-    types = dict(TPCH_TYPES); types["NCOP"] = nty
-    inputs = dict(db); inputs["NCOP"] = nested
-    q = nested_to_nested_query(2, "NCOP", nty)
-    prog = N.Program([N.Assignment("Q", q)])
-    sp = M.shred_program(prog, types, domain_elimination=True)
-    cp = CG.compile_program(sp, CATALOG)
-    env = CG.columnar_shred_inputs(inputs, types)
-    PN = 8
-    env = {k: b.resize(((b.capacity + PN - 1)//PN)*PN) for k, b in env.items()}
-    mesh = device_mesh_1d(PN)
-    man = sp.manifests["Q"]
-    names = [man.top] + list(man.dicts.values())
-    def fn(env_local, ctx):
-        o = CG.run_flat_program(cp, env_local, ExecSettings(dist=ctx))
-        return {k: o[k] for k in names}
-    direct = I.eval_expr(q, inputs)
-    for aware in (False, True):
-        for mode, kw in MODES:
-            t0 = time.perf_counter()
-            runner, res, metrics = compile_distributed(
-                fn, env, mesh, skew_default=aware, **kw)
-            cold = time.perf_counter() - t0
-            # steady state: the compiled program re-run on resident data
-            # (the serving case; compile/adaptive-probe cost amortized)
-            t0 = time.perf_counter()
-            for _ in range(WARM_ITERS):
-                res, _m = runner(env)
-                jax.block_until_ready(res)
-            warm = (time.perf_counter() - t0) / WARM_ITERS
-            parts = {(): res[man.top],
-                     **{p: res[n] for p, n in man.dicts.items()}}
-            ok = I.bags_equal(direct, CG.parts_to_rows(parts, q.ty))
-            keep = {k: int(v) for k, v in metrics.items()
-                    if not k.startswith("size_")}
-            out.append(dict(skew=skew, aware=aware, mode=mode,
-                            seconds=warm, cold_seconds=cold, ok=ok,
-                            **keep))
-print("JSON" + json.dumps(out))
-"""
-
 
 _AUTO_CHILD = r"""
 import os
@@ -180,15 +102,14 @@ for zipf in sweep:
         return CG.parts_to_rows(parts, q.ty)
 
     runners = {}
-    for mode in ("auto", "off", "always"):
+    for mode in ("auto", "off"):
         cp = CG.compile_program(
             sp, CATALOG, skew_stats=stats if mode == "auto" else None,
             skew_partitions=PN)
         CG.reset_trace_stats()
         t0 = time.perf_counter()
         runner, res, metrics = CG.compile_program_distributed(
-            cp, env, mesh, cap_factor=2.0, adaptive=True,
-            skew_default=(mode == "always"))
+            cp, env, mesh, cap_factor=2.0, adaptive=True)
         cold = time.perf_counter() - t0
         t0 = time.perf_counter()
         for _ in range(WARM_ITERS):
@@ -303,54 +224,7 @@ def run_auto(smoke: bool = False):
 
 
 def run():
-    run_legacy_vs_packed()
     run_auto(smoke=False)
-
-
-def run_legacy_vs_packed():
-    src = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
-                       "src")
-    bench = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
-    script = _CHILD % {"src": os.path.abspath(src),
-                       "bench": os.path.abspath(bench)}
-    res = subprocess.run([sys.executable, "-c", script],
-                         capture_output=True, text=True, timeout=3000)
-    if res.returncode != 0:
-        print(res.stdout[-2000:])
-        print(res.stderr[-2000:])
-        raise RuntimeError("skew benchmark child failed")
-    payload = [l for l in res.stdout.splitlines() if l.startswith("JSON")][0]
-    rows = json.loads(payload[4:])
-    for r in rows:
-        name = (f"skew{r['skew']}_{'aware' if r['aware'] else 'unaware'}"
-                f"_{r['mode']}")
-        assert r["ok"], f"{name} produced wrong results"
-        emit(name, r["seconds"] * 1e6,
-             f"shuffle_rows={r.get('shuffle_rows', 0)};"
-             f"overflow={r.get('overflow_rows', 0)};"
-             f"collectives={r.get('shuffle_collectives', 0)};"
-             f"elided={r.get('exchanges_elided', 0)};"
-             f"coldS={r.get('cold_seconds', 0):.2f};"
-             f"broadcastB={r.get('broadcast_bytes', 0)}")
-    # headline 1: skew-aware shuffle reduction at the highest skew
-    hi = {(r["aware"], r["mode"]): r for r in rows if r["skew"] == 2.0}
-    red = hi[(False, "packed")]["shuffle_rows"] \
-        / max(hi[(True, "packed")]["shuffle_rows"], 1)
-    emit("skew2.0_shuffle_reduction", 0.0, f"x{red:.2f}")
-    # headline 2: packed single-collective shuffle vs the legacy
-    # (PR 1) exchange at skew >= 1.2 — collectives and end-to-end time
-    for skew in (1.2, 2.0):
-        for aware in (False, True):
-            sel = {r["mode"]: r for r in rows
-                   if r["skew"] == skew and r["aware"] == aware}
-            leg, pk = sel["legacy"], sel["packed"]
-            speed = leg["seconds"] / max(pk["seconds"], 1e-9)
-            emit(f"skew{skew}_{'aware' if aware else 'unaware'}"
-                 f"_packed_speedup", 0.0,
-                 f"x{speed:.2f};collectives "
-                 f"{leg['shuffle_collectives']}->"
-                 f"{pk['shuffle_collectives']};"
-                 f"elided={pk['exchanges_elided']}")
 
 
 if __name__ == "__main__":

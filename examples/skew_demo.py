@@ -1,6 +1,10 @@
 """Skew-resilient distributed processing demo (paper §5) on 8 virtual
-devices: runs the same shredded query with and without skew-aware joins
-on Zipf-skewed data and prints the shuffle/broadcast/overflow metrics.
+devices: persists Zipf-skewed data, lets the stored heavy-key sketch
+and zone maps drive the compiler's ``SkewJoinP`` decision (DESIGN.md
+"Automated skew handling"), runs the same shredded query with the plain
+and the planned skew join, prints the shuffle/broadcast/overflow
+metrics, and rebinds a NEW heavy-key set on the warm runner with zero
+retraces.
 
     PYTHONPATH=src python examples/skew_demo.py
 """
@@ -12,15 +16,19 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tests"))
 
+import tempfile
+
 import jax
 
 from repro.core import codegen as CG
 from repro.core import interpreter as I
 from repro.core import materialization as M
 from repro.core import nrc as N
-from repro.core.plans import ExecSettings
+from repro.core import skew as SKM
+from repro.core.plans import SkewJoinP, _walk_plan, collect_plan_params
 from repro.core.unnesting import Catalog
-from repro.exec.dist import device_mesh_1d, run_distributed
+from repro.exec.dist import device_mesh_1d
+from repro.storage import StorageCatalog, table_stats
 from helpers import INPUT_TYPES, gen_cop, gen_parts, running_example_query
 
 print(f"devices: {len(jax.devices())}")
@@ -31,62 +39,41 @@ direct = I.eval_expr(running_example_query(), data)
 
 prog = N.Program([N.Assignment("Q", running_example_query())])
 sp = M.shred_program(prog, INPUT_TYPES, domain_elimination=True)
-cp = CG.compile_program(sp, Catalog(unique_keys={"Part__F": ("pid",)}))
+catalog = Catalog(unique_keys={"Part__F": ("pid",)})
 env = CG.columnar_shred_inputs(data, INPUT_TYPES)
 PN = 8
 env = {k: b.resize(((b.capacity + PN - 1) // PN) * PN)
        for k, b in env.items()}
 mesh = device_mesh_1d(PN)
 man = sp.manifests["Q"]
-names = [man.top] + list(man.dicts.values())
-
-
-def fn(env_local, ctx):
-    out = CG.run_flat_program(cp, env_local, ExecSettings(dist=ctx))
-    return {k: out[k] for k in names}
-
-
-for aware in (False, True):
-    out, metrics = run_distributed(fn, env, mesh, skew_default=aware,
-                                   cap_factor=16.0)
-    parts = {(): out[man.top], **{p: out[n] for p, n in man.dicts.items()}}
-    ok = I.bags_equal(direct, CG.parts_to_rows(parts,
-                                               running_example_query().ty))
-    label = "skew-aware " if aware else "skew-unaware"
-    print(f"{label}: correct={ok}  metrics={metrics}")
-print("note: the skew-aware join leaves heavy keys in place and "
-      "broadcasts the small build side (paper Fig. 6)")
-
-# --- automatic, compiler-decided skew (DESIGN.md "Automated skew
-# handling"): persist the dataset, let the streaming heavy-key sketch
-# + zone maps drive the SkewJoinP decision, rebind a NEW heavy-key set
-# on the warm runner with zero retraces ------------------------------------
-import tempfile
-
-from repro.core import skew as SKM
-from repro.core.plans import SkewJoinP, _walk_plan, collect_plan_params
-from repro.storage import StorageCatalog, table_stats
 
 cat = StorageCatalog(tempfile.mkdtemp())
 cat.writer("cop", INPUT_TYPES, chunk_rows=256).append(data)
-ds = cat.open("cop")
-stats = table_stats(ds)
-cp_auto = CG.compile_program(
-    sp, Catalog(unique_keys={"Part__F": ("pid",)}),
-    skew_stats=stats, skew_partitions=PN)
-n_sj = sum(1 for _, p in cp_auto.plans for s in _walk_plan(p)
-           if isinstance(s, SkewJoinP))
-print(f"automatic plan: {n_sj} SkewJoinP node(s), "
-      f"params={cp_auto.skew_params}")
-CG.reset_trace_stats()
-runner, out, metrics = CG.compile_program_distributed(
-    cp_auto, env, mesh, cap_factor=16.0)
-traces = CG.TRACE_STATS.get("traces", 0)
-if cp_auto.skew_params:
-    (name,) = collect_plan_params(cp_auto.graph)
+stats = table_stats(cat.open("cop"))
+
+
+def correct(out):
+    parts = {(): out[man.top], **{p: out[n] for p, n in man.dicts.items()}}
+    return I.bags_equal(direct, CG.parts_to_rows(
+        parts, running_example_query().ty))
+
+
+for label, skew_stats in (("plain join  ", None), ("planned skew", stats)):
+    cp = CG.compile_program(sp, catalog, skew_stats=skew_stats,
+                            skew_partitions=PN)
+    n_sj = sum(1 for _, p in cp.plans for s in _walk_plan(p)
+               if isinstance(s, SkewJoinP))
+    CG.reset_trace_stats()
+    runner, out, metrics = CG.compile_program_distributed(
+        cp, env, mesh, cap_factor=16.0)
+    print(f"{label}: {n_sj} SkewJoinP node(s), params={cp.skew_params}, "
+          f"correct={correct(out)}  metrics={metrics}")
+print("note: the planned skew join leaves heavy keys in place and "
+      "broadcasts the matching build rows (paper Fig. 6)")
+
+if cp.skew_params:
+    traces = CG.TRACE_STATS.get("traces", 0)
+    (name,) = collect_plan_params(cp.graph)
     out, metrics = runner(env, params={name: SKM.pad_heavy([7, 11, 13])})
-parts = {(): out[man.top], **{p: out[n] for p, n in man.dicts.items()}}
-ok = I.bags_equal(direct, CG.parts_to_rows(parts,
-                                           running_example_query().ty))
-print(f"planned skew: correct={ok}  retraces on new heavy set="
-      f"{CG.TRACE_STATS.get('traces', 0) - traces}")
+    print(f"rebound heavy set: correct={correct(out)}  retraces="
+          f"{CG.TRACE_STATS.get('traces', 0) - traces}")

@@ -158,7 +158,6 @@ def compile_program(sp: ShreddedProgram, catalog: Optional[Catalog] = None,
                     optimize: bool = True, cse: bool = True,
                     outputs: Optional[tuple] = None,
                     skew_stats: Optional[dict] = None,
-                    skew_mode: str = "auto",
                     skew_partitions: int = 8,
                     skew_threshold: float = 0.025,
                     hypercube_mode: str = "auto",
@@ -168,13 +167,13 @@ def compile_program(sp: ShreddedProgram, catalog: Optional[Catalog] = None,
     with _span("compile", kind="plan",
                assignments=len(sp.program.assignments)):
         return _compile_program_impl(
-            sp, catalog, optimize, cse, outputs, skew_stats, skew_mode,
+            sp, catalog, optimize, cse, outputs, skew_stats,
             skew_partitions, skew_threshold, hypercube_mode, cost_mode,
             observed_rows)
 
 
 def _compile_program_impl(sp, catalog, optimize, cse, outputs, skew_stats,
-                          skew_mode, skew_partitions, skew_threshold,
+                          skew_partitions, skew_threshold,
                           hypercube_mode, cost_mode="off",
                           observed_rows=None) -> CompiledProgram:
     """Compile the assignment sequence into a ProgramGraph.
@@ -187,12 +186,10 @@ def _compile_program_impl(sp, catalog, optimize, cse, outputs, skew_stats,
     and dictionary assignments are hash-consed into shared nodes.
 
     ``skew_stats`` ({bag: skew.TableStats}, typically from
-    ``storage.table_stats``) turns on the automatic skew pass
-    (``skew_mode="auto"``): joins whose probe-side heavy-hitter
-    statistics predict imbalance over ``skew_partitions`` become
-    ``SkewJoinP`` nodes with the heavy-key set lifted as a runtime
-    parameter. ``skew_mode="off"`` disables the pass regardless of
-    statistics (the forced-off baseline).
+    ``storage.table_stats``) turns on the automatic skew pass: joins
+    whose probe-side heavy-hitter statistics predict imbalance over
+    ``skew_partitions`` become ``SkewJoinP`` nodes with the heavy-key
+    set lifted as a runtime parameter.
 
     ``hypercube_mode="auto"`` additionally lets the HyperCube pass
     rewrite multiway equi-join chains to one-round ``MultiJoinP``
@@ -213,7 +210,6 @@ def _compile_program_impl(sp, catalog, optimize, cse, outputs, skew_stats,
     formula estimates with ground truth on recompile — the feedback
     loop. ``cost_mode="off"`` (the default) keeps every decision
     byte-identical to the pre-cost compiler."""
-    assert skew_mode in ("auto", "off"), skew_mode
     assert hypercube_mode in ("auto", "off"), hypercube_mode
     assert cost_mode in ("auto", "off"), cost_mode
     catalog = catalog or Catalog()
@@ -246,7 +242,7 @@ def _compile_program_impl(sp, catalog, optimize, cse, outputs, skew_stats,
             # decision (a): costed join ordering, before the skew and
             # hypercube passes so both see the chosen chain order
             order_join_chains(graph, estimator)
-        if skew_stats is not None and skew_mode == "auto":
+        if skew_stats is not None:
             skew_info = apply_skew_program(graph, skew_stats,
                                            n_partitions=skew_partitions,
                                            threshold=skew_threshold,
@@ -565,8 +561,7 @@ def unshred_parts(parts: Dict[tuple, FlatBag]) -> Dict[tuple, CSRLevel]:
             continue
         key = bag.col("label").astype(jnp.int64)
         key = jnp.where(bag.valid, key, X.I64_MAX)
-        if X.ORDER_AWARE and bag.props.invalid_last \
-                and bag.props.sorted_prefix(("label",)):
+        if bag.props.invalid_last and bag.props.sorted_prefix(("label",)):
             # dictionary already clustered by label (Gamma_u children of
             # an invalid-last input): the cogroup sort is free
             out[path] = CSRLevel(bag, key)

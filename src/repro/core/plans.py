@@ -63,7 +63,6 @@ class JoinP(Plan):
     unique_right: bool = True    # fk join (capacity-preserving) if True
     expansion: float = 1.0       # general-join capacity factor
     broadcast: bool = False      # distribution hint: broadcast right side
-    skew_aware: bool = False     # §5 skew-triple processing
     matched_col: str = "__matched"
 
 
@@ -231,8 +230,6 @@ def plan_pretty(p: Plan, indent: int = 0) -> str:
         mods = []
         if p.broadcast:
             mods.append("broadcast")
-        if p.skew_aware:
-            mods.append("skew")
         if not p.unique_right:
             mods.append(f"general x{p.expansion}")
         mod = ("{" + ",".join(mods) + "}") if mods else ""
@@ -416,19 +413,15 @@ def _scan(env: Dict[str, FlatBag], name: str, alias: str,
         _storage_ensure(env, name, None, params)
     bag = env[name]
     memo_key = (alias, with_rowid)
-    if X.ORDER_AWARE:
-        hit = bag.props.scan_memo.get(memo_key)
-        if hit is not None:
-            return hit
+    hit = bag.props.scan_memo.get(memo_key)
+    if hit is not None:
+        return hit
     data = {f"{alias}.{c}": bag.data[c] for c in bag.data}
     if with_rowid:
         data[f"{alias}.__rowid"] = jnp.arange(bag.capacity, dtype=jnp.int64)
-    props = None
-    if X.ORDER_AWARE:
-        props = bag.props.renamed({c: f"{alias}.{c}" for c in bag.data})
+    props = bag.props.renamed({c: f"{alias}.{c}" for c in bag.data})
     out = FlatBag(data, bag.valid, props)
-    if X.ORDER_AWARE:
-        bag.props.scan_memo[memo_key] = out
+    bag.props.scan_memo[memo_key] = out
     return out
 
 
@@ -471,33 +464,32 @@ def _eval_plan_node(p: Plan, env: Dict[str, FlatBag],
         if p.extend:
             return child.with_columns(**cols)
         out = X.project(child, cols)
-        if X.ORDER_AWARE:
-            # a projection is row-local (rows and validity unchanged):
-            # physical properties survive for columns that pass through
-            # as bare Vars, under the output name. Entries referencing
-            # any non-passthrough column are dropped, which also guards
-            # against an output name shadowing an unrelated child column.
-            passthru = {e.name: o for o, e in p.outputs
-                        if isinstance(e, N.Var)}
-            cp = child.props
-            sb = []
-            for c in cp.sorted_by or ():
-                if c not in passthru:
-                    break
-                sb.append(passthru[c])
-            key_cache = {tuple(passthru[c] for c in cols_): v
-                         for cols_, v in cp.key_cache.items()
-                         if all(c in passthru for c in cols_)}
-            part = cp.partitioning
-            part = tuple(passthru[c] for c in part) \
-                if part is not None and all(c in passthru for c in part) \
-                else None
-            if sb or key_cache or part:
-                from repro.columnar.props import PhysicalProps
-                out = out.with_props(PhysicalProps(
-                    key_cache=key_cache, sorted_by=tuple(sb) or None,
-                    invalid_last=cp.invalid_last,
-                    partitioning=part))
+        # a projection is row-local (rows and validity unchanged):
+        # physical properties survive for columns that pass through
+        # as bare Vars, under the output name. Entries referencing
+        # any non-passthrough column are dropped, which also guards
+        # against an output name shadowing an unrelated child column.
+        passthru = {e.name: o for o, e in p.outputs
+                    if isinstance(e, N.Var)}
+        cp = child.props
+        sb = []
+        for c in cp.sorted_by or ():
+            if c not in passthru:
+                break
+            sb.append(passthru[c])
+        key_cache = {tuple(passthru[c] for c in cols_): v
+                     for cols_, v in cp.key_cache.items()
+                     if all(c in passthru for c in cols_)}
+        part = cp.partitioning
+        part = tuple(passthru[c] for c in part) \
+            if part is not None and all(c in passthru for c in part) \
+            else None
+        if sb or key_cache or part:
+            from repro.columnar.props import PhysicalProps
+            out = out.with_props(PhysicalProps(
+                key_cache=key_cache, sorted_by=tuple(sb) or None,
+                invalid_last=cp.invalid_last,
+                partitioning=part))
         return out
     if isinstance(p, JoinP):
         left = eval_plan(p.left, env, s)
@@ -577,7 +569,7 @@ def _eval_ref(p: RefP, env: Dict[str, FlatBag]) -> FlatBag:
         return bag
     data = {mapping.get(c, c): a for c, a in bag.data.items()}
     props = None
-    if X.ORDER_AWARE and bag._props is not None:
+    if bag._props is not None:
         props = bag.props.renamed(mapping)
     return FlatBag(data, bag.valid, props)
 
@@ -647,8 +639,7 @@ def _exec_join(p: JoinP, left: FlatBag, right: FlatBag,
     if s.dist is not None:
         return s.dist.join(left, right, p.left_on, p.right_on, how=p.how,
                            unique_right=p.unique_right,
-                           broadcast=p.broadcast, skew_aware=p.skew_aware,
-                           expansion=p.expansion)
+                           broadcast=p.broadcast, expansion=p.expansion)
     if p.unique_right:
         bag = X.fk_join(left, right, p.left_on, p.right_on, how=p.how,
                         use_kernel=s.use_kernel)
@@ -754,7 +745,7 @@ def _pushdown(p: Plan, needed: Optional[set],
         return JoinP(_pushdown(p.left, ln, ref_needs),
                      _pushdown(p.right, rn, ref_needs),
                      p.left_on, p.right_on, p.how, p.unique_right,
-                     p.expansion, p.broadcast, p.skew_aware, p.matched_col)
+                     p.expansion, p.broadcast, p.matched_col)
     if isinstance(p, SumAggP):
         cn = set(p.keys) | set(p.vals)
         return SumAggP(_pushdown(p.child, cn, ref_needs), p.keys, p.vals,
@@ -780,7 +771,7 @@ def _pushdown(p: Plan, needed: Optional[set],
         nj = JoinP(_pushdown(j.left, cn | set(j.left_on), ref_needs),
                    _pushdown(j.right, cn | set(j.right_on), ref_needs),
                    j.left_on, j.right_on, j.how, j.unique_right,
-                   j.expansion, j.broadcast, j.skew_aware, j.matched_col)
+                   j.expansion, j.broadcast, j.matched_col)
         return FusedJoinAggP(nj, p.keys, p.vals, p.local_preagg,
                              p.exchange_on)
     if isinstance(p, SkewJoinP):
@@ -839,7 +830,7 @@ def push_aggregation(p: Plan) -> Plan:
             inner = SumAggP(j.left, keys_below, p.vals)
             new_join = JoinP(inner, j.right, j.left_on, j.right_on, j.how,
                              j.unique_right, j.expansion, j.broadcast,
-                             j.skew_aware, j.matched_col)
+                             j.matched_col)
             return SumAggP(new_join, p.keys, p.vals)
     # recurse
     for attr in ("child", "left", "right", "parent"):
@@ -991,7 +982,7 @@ def push_order(p: Plan, desired: Optional[tuple] = None) -> Plan:
         return JoinP(push_order(p.left, desired),
                      push_order(p.right, tuple(p.right_on)),
                      p.left_on, p.right_on, p.how, p.unique_right,
-                     p.expansion, p.broadcast, p.skew_aware, p.matched_col)
+                     p.expansion, p.broadcast, p.matched_col)
     if isinstance(p, OuterUnnestP):
         return OuterUnnestP(push_order(p.parent, desired), p.child_bag,
                             p.alias, p.parent_label, p.child_label,
@@ -1043,8 +1034,6 @@ def delivered_partitioning(p: Plan) -> Optional[tuple]:
     if isinstance(p, JoinP):
         if p.broadcast:
             return delivered_partitioning(p.left)  # probe side stays put
-        if p.skew_aware:
-            return None     # light+heavy union mixes placements
         ld = delivered_partitioning(p.left)
         if ld is not None and set(ld) <= set(p.left_on):
             return ld       # probe side elided: placement unchanged
@@ -1118,14 +1107,14 @@ def push_partitioning(p: Plan, desired: Optional[tuple] = None) -> Plan:
         nj = JoinP(push_partitioning(j.left, tuple(j.left_on)),
                    push_partitioning(j.right, tuple(j.right_on)),
                    j.left_on, j.right_on, j.how, j.unique_right,
-                   j.expansion, j.broadcast, j.skew_aware, j.matched_col)
+                   j.expansion, j.broadcast, j.matched_col)
         return FusedJoinAggP(nj, p.keys, p.vals, p.local_preagg,
                              exchange_on=ex)
     if isinstance(p, JoinP):
         return JoinP(push_partitioning(p.left, tuple(p.left_on)),
                      push_partitioning(p.right, tuple(p.right_on)),
                      p.left_on, p.right_on, p.how, p.unique_right,
-                     p.expansion, p.broadcast, p.skew_aware, p.matched_col)
+                     p.expansion, p.broadcast, p.matched_col)
     if isinstance(p, SelectP):
         return SelectP(push_partitioning(p.child, desired), p.pred)
     if isinstance(p, MapP):
@@ -1350,7 +1339,7 @@ def _plan_sig(p: Plan, canon: _Canon):
             else p.matched_col
         return ("join", l, r, canon.cols(p.left_on),
                 canon.cols(p.right_on), p.how, p.unique_right,
-                p.expansion, p.broadcast, p.skew_aware, mc)
+                p.expansion, p.broadcast, mc)
     if isinstance(p, SumAggP):
         c = _plan_sig(p.child, canon)
         return ("sum", c, canon.cols(p.keys), canon.cols(p.vals),
@@ -1703,7 +1692,7 @@ def apply_skew_program(graph: ProgramGraph, stats: Dict[str, object],
     param_of: Dict[Tuple[str, str], str] = {}
 
     def probe_heavy(j: JoinP):
-        if j.broadcast or j.skew_aware or len(j.left_on) != 1:
+        if j.broadcast or len(j.left_on) != 1:
             return None
         head, sep, attr = j.left_on[0].partition(".")
         if not sep:
@@ -1792,9 +1781,9 @@ def apply_skew_program(graph: ProgramGraph, stats: Dict[str, object],
 def _peel_join_chain(p: Plan, min_joins: int):
     """Maximal left-deep chain of directly nested inner JoinP /
     SkewJoinP under ``p``: returns (base, [(JoinP, heavy_param,
-    heavy_default), ...] innermost-first) or None. Outer joins,
-    broadcast and legacy skew_aware joins break the chain — only the
-    inner hash-exchange cascade is replaceable by one round."""
+    heavy_default), ...] innermost-first) or None. Outer and broadcast
+    joins break the chain — only the inner hash-exchange cascade is
+    replaceable by one round."""
     stages = []
     cur = p
     while True:
@@ -1803,8 +1792,7 @@ def _peel_join_chain(p: Plan, min_joins: int):
         if isinstance(j, SkewJoinP):
             hp, hd = j.heavy_param, j.heavy_default
             j = j.join
-        if not isinstance(j, JoinP) or j.how != "inner" or j.broadcast \
-                or j.skew_aware:
+        if not isinstance(j, JoinP) or j.how != "inner" or j.broadcast:
             break
         stages.append((j, hp, hd))
         cur = j.left

@@ -1,16 +1,12 @@
 """Skew-resilient processing (paper §5).
 
-Heavy-key detection by sampling, skew-triples, and membership tests.
-The paper samples tuples per partition and calls a key *heavy* when it
-covers >= ``threshold`` of the sample; with threshold t there can be at
-most ceil(1/t) heavy keys per partition (the paper's 2.5% -> 40 keys),
-which bounds the broadcast cost of the heavy set.
-
-The jnp helpers run both locally and inside shard_map (the distributed
-variants all_gather the per-partition candidates).
-
-Since the compiler-integrated skew handling (DESIGN.md "Automated skew
-handling") this module also owns the *plan-time* statistics side:
+The paper calls a key *heavy* when it covers >= ``threshold`` of a
+partition's rows; with threshold t there can be at most ceil(1/t) heavy
+keys (the paper's 2.5% -> 40 keys), which bounds the broadcast cost of
+the heavy set. Skew handling is a compiler decision (DESIGN.md
+"Automated skew handling"): the heavy-key set is chosen at plan time
+and bound as a runtime parameter, and ``is_member`` tests each row
+against it. This module owns the plan-time statistics side:
 
 * ``HeavyKeySketch`` — a streaming Misra-Gries (space-saving) heavy-
   hitter sketch, updated host-side by ``storage.DatasetWriter`` on every
@@ -29,11 +25,9 @@ from __future__ import annotations
 from dataclasses import dataclass, field as dc_field
 from typing import Dict, List, Optional, Sequence, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.columnar.table import FlatBag
 from repro.exec import ops as X
 
 I64_MAX = X.I64_MAX
@@ -42,46 +36,6 @@ MAX_HEAVY = 40
 """Static size of every runtime heavy-key set (the paper's 2.5% -> 40
 keys bound). One shape for all bindings is what lets a warm plan rebind
 a *different* heavy-key set with zero retraces."""
-
-
-def heavy_keys_local(key: jnp.ndarray, valid: jnp.ndarray,
-                     sample: int = 256, threshold: float = 0.025,
-                     max_heavy: Optional[int] = None) -> jnp.ndarray:
-    """Per-partition heavy-key candidates from a strided sample.
-
-    Returns a static-size array (max_heavy,) padded with I64_MAX.
-    max_heavy defaults to ceil(1/threshold) — the paper's bound."""
-    cap = key.shape[0]
-    if max_heavy is None:
-        max_heavy = max(int(1.0 / threshold), 1)
-    sample = min(sample, cap)
-    stride = max(cap // sample, 1)
-    idx = jnp.arange(sample) * stride
-    skey = jnp.where(valid[idx], key[idx], I64_MAX)
-    # count sampled frequency per key (sort + run lengths)
-    sk = jnp.sort(skey)
-    start = jnp.concatenate([jnp.ones(1, bool), sk[1:] != sk[:-1]])
-    seg = jnp.cumsum(start.astype(jnp.int32)) - 1
-    ones = (sk != I64_MAX).astype(jnp.int32)
-    counts = jax.ops.segment_sum(ones, seg, num_segments=sample)
-    firsts = jax.ops.segment_min(jnp.arange(sample), seg,
-                                 num_segments=sample)
-    need = max(int(threshold * sample), 1)
-    is_heavy_seg = counts >= need
-    # rank heavy segments by -count and take top max_heavy
-    order = jnp.argsort(jnp.where(is_heavy_seg, -counts, 1))
-    top = order[:max_heavy]
-    fidx = jnp.clip(firsts[top], 0, sample - 1)
-    keys = jnp.where(is_heavy_seg[top], sk[fidx], I64_MAX)
-    return keys
-
-
-def merge_heavy(candidates: jnp.ndarray) -> jnp.ndarray:
-    """Deduplicate an array of heavy-key candidates (padded I64_MAX),
-    returning it sorted (still padded)."""
-    sk = jnp.sort(candidates.reshape(-1))
-    dup = jnp.concatenate([jnp.zeros(1, bool), sk[1:] == sk[:-1]])
-    return jnp.sort(jnp.where(dup, I64_MAX, sk))
 
 
 def is_member(key: jnp.ndarray, heavy_sorted: jnp.ndarray,
@@ -96,18 +50,6 @@ def is_member(key: jnp.ndarray, heavy_sorted: jnp.ndarray,
     pos = jnp.searchsorted(heavy_sorted, key)
     pos = jnp.clip(pos, 0, heavy_sorted.shape[0] - 1)
     return (heavy_sorted[pos] == key) & (key != I64_MAX)
-
-
-def split_skew(bag: FlatBag, key_cols, heavy_sorted: jnp.ndarray,
-               key: Optional[jnp.ndarray] = None
-               ) -> Tuple[FlatBag, FlatBag]:
-    """Split a bag into (light, heavy) components of a skew-triple.
-    ``key`` optionally supplies the pre-packed key so the skew path
-    (detect -> split -> exchange) packs each key set exactly once."""
-    if key is None:
-        key = X.pack_keys(bag, key_cols)
-    hv = is_member(key, heavy_sorted)
-    return bag.mask(~hv), bag.mask(hv)
 
 
 def pad_heavy(keys: Sequence[int], max_heavy: int = MAX_HEAVY

@@ -6,11 +6,10 @@ per-destination buckets* (the MoE-dispatch pattern): skewed keys
 overflow their bucket instead of spilling to disk — overflow is counted
 and reported, the TPU-native analogue of the paper's crashed bars.
 
-The default (**packed**) exchange is a sort-based packed shuffle:
+The exchange is a sort-based packed shuffle:
 
 * rows are routed by a *destination sort* (argsort by ``hash(key) % P``,
-  cached per key set in ``PhysicalProps.route_cache``) instead of the
-  seed's dense one-hot/cumsum scatter;
+  cached per key set in ``PhysicalProps.route_cache``);
 * every column ships in ONE collective — the columns are bit-cast to
   int64 lanes and stacked into a single ``(P, bucket, n_lanes)`` wire
   buffer (plus one packed-key lane seeding the receiver's key cache and
@@ -29,14 +28,13 @@ The default (**packed**) exchange is a sort-based packed shuffle:
   tradeoff for light keys while keeping metered overflow as the skew
   safety valve.
 
-``shuffle_mode="legacy"`` selects the seed path (one-hot scatter, one
-collective per column, no elision) — the benchmarks' baseline.
-
-Broadcast joins use ``all_gather`` of the small side. The skew-aware
-join (paper Fig. 6) exchanges only the light component and gathers the
-heavy rows of the build side, leaving heavy probe rows in place; the
-light+heavy unions compact back to the pre-split capacity
-(``concat_compact``) instead of compounding buffer growth.
+Broadcast joins use ``all_gather`` of the small side. The skew join
+(paper Fig. 6), planned by the compiler (``plans.SkewJoinP``) with its
+heavy-key set bound as a runtime parameter, exchanges only the light
+component and gathers the heavy rows of the build side, leaving heavy
+probe rows in place; the light+heavy union compacts back to the
+pre-split capacity (``concat_compact``) instead of compounding buffer
+growth.
 
 All operators run *inside* shard_map over a 1-D partition axis; a
 ``DistContext`` carries the axis name and a metrics accumulator
@@ -52,7 +50,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.columnar.table import FlatBag, concat_bags, concat_compact
+from repro.columnar.props import PhysicalProps
+from repro.columnar.table import FlatBag, concat_compact
 from repro.core import skew as SK
 from repro.errors import ExchangeError
 from repro.faults import FAULTS
@@ -99,18 +98,12 @@ class DistContext:
     """Collective operators + metering for one shard_map region."""
 
     def __init__(self, axis: str, n_partitions: int,
-                 cap_factor: float = 2.0, sample: int = 256,
-                 threshold: float = 0.025, skew_default: bool = False,
-                 packed: bool = True,
+                 cap_factor: float = 2.0,
                  size_plan: Optional[Sequence[int]] = None,
                  use_kernel: bool = False):
         self.axis = axis
         self.P = n_partitions
         self.cap_factor = cap_factor
-        self.sample = sample
-        self.threshold = threshold
-        self.skew_default = skew_default
-        self.packed = packed
         self.size_plan = size_plan
         self.use_kernel = use_kernel
         self.metrics: Dict[str, jnp.ndarray] = {}
@@ -176,10 +169,10 @@ class DistContext:
         ``key_cols`` (``PhysicalProps.partitioning``), equal keys are
         already co-located and the exchange is a no-op.
 
-        Wire format (packed mode): every column bit-cast to an int64
-        lane, stacked with a packed-key lane (pre-seeding the receiving
-        key cache) and a validity lane into one ``(P, bucket, n_lanes)``
-        buffer — one ``all_to_all`` total. Within each (sender, dest)
+        Wire format: every column bit-cast to an int64 lane, stacked
+        with a packed-key lane (pre-seeding the receiving key cache)
+        and a validity lane into one ``(P, bucket, n_lanes)`` buffer —
+        one ``all_to_all`` total. Within each (sender, dest)
         block rows arrive contiguously in sender order; slots past the
         sender's count arrive zero with validity 0."""
         rule = FAULTS.hit("dist.exchange", keys=tuple(key_cols))
@@ -187,9 +180,7 @@ class DistContext:
             raise ExchangeError(
                 f"injected exchange failure (keys={tuple(key_cols)})")
         key_cols = tuple(key_cols)
-        if not self.packed:
-            return self._exchange_legacy(bag, key_cols, keep, key)
-        if X.ORDER_AWARE and bag.props.partitioned_for(key_cols):
+        if bag.props.partitioned_for(key_cols):
             _scount("exchange_elided")
             return bag if keep is None else bag.mask(keep)
         _scount("exchanges")
@@ -201,7 +192,7 @@ class DistContext:
 
         # -- destination-sort routing (cached when validity untouched) --
         route = None
-        if X.ORDER_AWARE and keep is None:
+        if keep is None:
             route = bag.props.route_cache.get(key_cols)
             if route is not None:
                 _scount("route_reuse")
@@ -214,7 +205,7 @@ class DistContext:
                 jnp.ones(cap, jnp.int32), destk, num_segments=Pn + 1)[:Pn]
             offsets = jnp.cumsum(counts) - counts
             route = (order, counts, offsets)
-            if X.ORDER_AWARE and keep is None and X._cache_ok(bag, order):
+            if keep is None and X._cache_ok(bag, order):
                 bag.props.route_cache[key_cols] = route
         order, counts, offsets = route
 
@@ -234,15 +225,13 @@ class DistContext:
         sent = jnp.sum(jnp.minimum(counts, bucket))
         self._add("overflow_rows", jnp.sum(jnp.maximum(counts - bucket, 0)))
         self._add("shuffle_rows", sent)
-        # order-aware exchanges ship the packed key as one extra lane
-        key_lane = 8 if X.ORDER_AWARE else 0
-        self._add("shuffle_bytes", sent * (bag.row_bytes() + key_lane))
+        # the packed key ships as one extra 8-byte lane
+        self._add("shuffle_bytes", sent * (bag.row_bytes() + 8))
 
         # -- pack: one int64 lane per column + key + validity -----------
         names = bag.columns
         lanes = [X._to_i64_bits(bag.data[n]) for n in names]
-        if X.ORDER_AWARE:
-            lanes.append(key)
+        lanes.append(key)
         lanes.append(valid.astype(jnp.int64))
         mat = jnp.stack(lanes, axis=1)                    # (cap, n_lanes)
         slot = jnp.arange(Pn * bucket)
@@ -277,57 +266,8 @@ class DistContext:
         out_data = {n: X._from_i64_bits(lane(i), bag.data[n].dtype)
                     for i, n in enumerate(names)}
         vrecv = lane(len(lanes) - 1) != 0
-        props = None
-        if X.ORDER_AWARE:
-            from repro.columnar.props import PhysicalProps
-            props = PhysicalProps(key_cache={key_cols: lane(len(names))},
-                                  partitioning=key_cols)
-        return FlatBag(out_data, vrecv, props)
-
-    def _exchange_legacy(self, bag: FlatBag, key_cols: Tuple[str, ...],
-                         keep: Optional[jnp.ndarray],
-                         key: Optional[jnp.ndarray]) -> FlatBag:
-        """Seed-era exchange: dense one-hot/cumsum scatter and one
-        ``all_to_all`` per column — kept as the benchmarks' baseline
-        (``shuffle_mode="legacy"``)."""
-        _scount("exchanges")
-        cap = bag.capacity
-        Pn = self.P
-        bucket = max(int(cap * self.cap_factor) // Pn, 1)
-        if key is None:
-            key = X.pack_keys(bag, key_cols)
-        valid = bag.valid if keep is None else (bag.valid & keep)
-        dest = (mix64(key) % Pn).astype(jnp.int32)
-        dest = jnp.where(valid, dest, 0)
-        onehot = (dest[:, None] == jnp.arange(Pn)[None, :]) & valid[:, None]
-        pos = jnp.cumsum(onehot.astype(jnp.int32), axis=0) - 1
-        pos = jnp.take_along_axis(pos, dest[:, None], axis=1)[:, 0]
-        ok = valid & (pos < bucket)
-        self._add("overflow_rows", jnp.sum(valid & (pos >= bucket)))
-        self._add("shuffle_rows", jnp.sum(ok))
-        key_lane = 8 if X.ORDER_AWARE else 0
-        self._add("shuffle_bytes", jnp.sum(ok) * (bag.row_bytes() + key_lane))
-
-        pos_safe = jnp.where(ok, pos, bucket)  # out-of-bounds -> dropped
-
-        def scatter(col):
-            buf = jnp.zeros((Pn, bucket), col.dtype)
-            return buf.at[dest, pos_safe].set(jnp.where(ok, col, 0),
-                                              mode="drop")
-
-        def a2a(buf):
-            _scount("collectives")
-            return jax.lax.all_to_all(buf, self.axis, split_axis=0,
-                                      concat_axis=0,
-                                      tiled=False).reshape(Pn * bucket)
-
-        out_data = {n: a2a(scatter(a)) for n, a in bag.data.items()}
-        vrecv = a2a(jnp.zeros((Pn, bucket), bool).at[dest, pos_safe].set(
-            ok, mode="drop"))
-        props = None
-        if X.ORDER_AWARE:
-            from repro.columnar.props import PhysicalProps
-            props = PhysicalProps(key_cache={key_cols: a2a(scatter(key))})
+        props = PhysicalProps(key_cache={key_cols: lane(len(names))},
+                              partitioning=key_cols)
         return FlatBag(out_data, vrecv, props)
 
     # -- broadcast (all_gather) -----------------------------------------
@@ -343,13 +283,7 @@ class DistContext:
         self._add("broadcast_bytes",
                   jax.lax.psum(jnp.sum(valid), self.axis)
                   * bag.row_bytes() * (self.P - 1) // self.P)
-        if not self.packed:
-            _scount("collectives", len(bag.data) + 1)
-            data = {n: jax.lax.all_gather(a, self.axis, tiled=True)
-                    for n, a in bag.data.items()}
-            v = jax.lax.all_gather(valid, self.axis, tiled=True)
-            return FlatBag(data, v)
-        # packed: same single-collective column batching as exchange
+        # the same single-collective column batching as exchange
         names = bag.columns
         lanes = [X._to_i64_bits(bag.data[n]) for n in names]
         lanes.append(valid.astype(jnp.int64))
@@ -363,14 +297,12 @@ class DistContext:
     # -- joins -----------------------------------------------------------
     def join(self, left: FlatBag, right: FlatBag, left_on, right_on,
              how: str = "inner", unique_right: bool = True,
-             broadcast: bool = False, skew_aware: bool = False,
-             expansion: float = 4.0,
+             broadcast: bool = False, expansion: float = 4.0,
              heavy_keys: Optional[jnp.ndarray] = None) -> FlatBag:
         """``heavy_keys`` (compiler-planned skew, ``plans.SkewJoinP``)
         supplies the heavy-key set as a runtime value — a padded int64
-        array bound per call — instead of the per-call sampling of
-        ``skew_aware``. Both route through the same light-exchange +
-        heavy-broadcast skew triple."""
+        array bound per call — and routes the join through the
+        light-exchange + heavy-broadcast skew triple."""
         if broadcast:
             rall = self.gather_all(right)
             return self._local_join(left, rall, left_on, right_on, how,
@@ -379,11 +311,7 @@ class DistContext:
             _scount("skew_join_planned")
             return self._skew_join(left, right, left_on, right_on, how,
                                    unique_right, expansion,
-                                   heavy=heavy_keys)
-        if skew_aware or self.skew_default:
-            _scount("skew_join_sampled")
-            return self._skew_join(left, right, left_on, right_on, how,
-                                   unique_right, expansion)
+                                   heavy_keys)
         lk, rk = self._copartition_keys(left, right, left_on, right_on)
         lex = self._side_exchange(left, lk)
         rex = self._side_exchange(right, rk)
@@ -412,8 +340,6 @@ class DistContext:
         the same positional selection, the join exchanges neither.
         Returns ``(left_key, right_key)`` with ``None`` meaning elide."""
         left_on, right_on = tuple(left_on), tuple(right_on)
-        if not (self.packed and X.ORDER_AWARE):
-            return left_on, right_on
 
         def sel(part, on):
             if not part:
@@ -445,20 +371,16 @@ class DistContext:
         return bag
 
     def _skew_join(self, left, right, left_on, right_on, how,
-                   unique_right, expansion, heavy=None):
+                   unique_right, expansion, heavy):
         """Paper Fig. 6: split the probe side by heavy keys; exchange the
         light component; leave heavy probe rows in place and broadcast
         the matching build rows. Each key set is packed once and
-        threaded through detection, split and exchange. ``heavy``
-        (planned skew) supplies the key set directly — sorted here so
-        any runtime binding order works with the searchsorted member
-        test — replacing the sample + all_gather detection round."""
+        threaded through split and exchange. ``heavy`` is the planned
+        key set, sorted here so any runtime binding order works with the
+        searchsorted member test."""
         left_on, right_on = tuple(left_on), tuple(right_on)
         lkey = X.pack_keys(left, left_on)
-        if heavy is not None:
-            hk = jnp.sort(heavy.astype(jnp.int64))
-        else:
-            hk = self.heavy_keys(left, left_on, key=lkey)
+        hk = jnp.sort(heavy.astype(jnp.int64))
         heavy_mask = SK.is_member(lkey, hk,
                                   use_kernel=self.use_kernel) & left.valid
         # light plan: standard exchange join (co-partition aware)
@@ -478,13 +400,11 @@ class DistContext:
         return self._union_compact(light, heavy)
 
     def _union_compact(self, light: FlatBag, heavy: FlatBag) -> FlatBag:
-        """Union the light/heavy results of a skew op. Packed mode
-        compacts back to the larger of the two capacities (adaptively
-        regrown when the valid counts demand more) instead of letting
-        every skew op compound ``P*bucket + cap``; the padding that
-        remains and any dropped rows are metered."""
-        if not self.packed:
-            return concat_bags(light, heavy)
+        """Union the light/heavy results of a skew op, compacted back to
+        the larger of the two capacities (adaptively regrown when the
+        valid counts demand more) instead of letting every skew op
+        compound ``P*bucket + cap``; the padding that remains and any
+        dropped rows are metered."""
         site, target = self._size_site(max(light.capacity, heavy.capacity))
         need = jnp.sum(light.valid.astype(jnp.int64)) \
             + jnp.sum(heavy.valid.astype(jnp.int64))
@@ -675,18 +595,6 @@ class DistContext:
                                    st.unique_right, st.expansion)
         return acc
 
-    # -- heavy-key detection (sampled, then gathered) ---------------------
-    def heavy_keys(self, bag: FlatBag, key_cols,
-                   key: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-        if key is None:
-            key = X.pack_keys(bag, key_cols)
-        local = SK.heavy_keys_local(key, bag.valid, sample=self.sample,
-                                    threshold=self.threshold)
-        self._add("broadcast_bytes", local.shape[0] * 8 * (self.P - 1))
-        _scount("collectives")
-        allc = jax.lax.all_gather(local, self.axis, tiled=True)
-        return SK.merge_heavy(allc)
-
     # -- aggregation -------------------------------------------------------
     def sum_by(self, bag: FlatBag, keys, vals, local_preagg: bool = True,
                use_kernel: bool = False,
@@ -716,20 +624,6 @@ class DistContext:
         assert set(ex_key) <= set(cols), (ex_key, cols)
         ex = self.exchange(local, ex_key)
         return X.dedup(ex, cols)
-
-    # -- BagToDict (skew-aware label repartition, Fig. 6 last row) --------
-    def bag_to_dict(self, bag: FlatBag, skew_aware: bool = True) -> FlatBag:
-        if not skew_aware:
-            return self.exchange(bag, ("label",))
-        key = X.pack_keys(bag, ("label",))
-        hk = self.heavy_keys(bag, ("label",), key=key)
-        heavy_mask = SK.is_member(key, hk,
-                                  use_kernel=self.use_kernel) & bag.valid
-        light = self.exchange(bag, ("label",), keep=~heavy_mask, key=key)
-        heavy = bag.mask(heavy_mask)
-        # heavy labels keep their current location (skew resilience);
-        # compact the light+heavy union back toward pre-split capacity.
-        return self._union_compact(light, heavy)
 
 
 # ---------------------------------------------------------------------------
@@ -836,9 +730,7 @@ class DistRunner:
 def shard_program(fn: Callable, mesh: Mesh, axis: str = "data",
                   has_params: bool = False, jit: bool = True,
                   size_plan: Optional[Sequence[int]] = None,
-                  cap_factor: float = 2.0, threshold: float = 0.025,
-                  skew_default: bool = False,
-                  shuffle_mode: str = "packed",
+                  cap_factor: float = 2.0,
                   use_kernel: bool = False) -> Callable:
     """``fn(env_local, ctx[, params_local])`` as one SPMD program over
     ``mesh[axis]``, returning ``(outputs, metrics)``; nothing runs.
@@ -849,9 +741,7 @@ def shard_program(fn: Callable, mesh: Mesh, axis: str = "data",
     n = mesh.shape[axis]
 
     def make_ctx():
-        return DistContext(axis, n, cap_factor=cap_factor, sample=256,
-                           threshold=threshold, skew_default=skew_default,
-                           packed=(shuffle_mode == "packed"),
+        return DistContext(axis, n, cap_factor=cap_factor,
                            size_plan=size_plan, use_kernel=use_kernel)
 
     if has_params:
@@ -875,10 +765,7 @@ def compile_distributed(
         fn: Callable[[Dict[str, FlatBag], DistContext], dict],
         env: Dict[str, FlatBag], mesh: Mesh,
         axis: str = "data", cap_factor: float = 2.0,
-        skew_default: bool = False,
-        threshold: float = 0.025,
         jit: bool = True,
-        shuffle_mode: str = "packed",
         use_kernel: bool = False,
         adaptive: bool = False,
         max_retries: int = 3,
@@ -916,7 +803,6 @@ def compile_distributed(
     for k, b in env.items():
         assert b.capacity % n == 0, (
             f"bag {k} capacity {b.capacity} not divisible by {n} partitions")
-    assert shuffle_mode in ("packed", "legacy"), shuffle_mode
 
     has_params = params is not None
     pvals = {k: jnp.asarray(v) for k, v in (params or {}).items()}
@@ -927,17 +813,13 @@ def compile_distributed(
         reset_shuffle_stats()
         sm = shard_program(fn, mesh, axis=axis, has_params=has_params,
                            jit=jit, size_plan=size_plan,
-                           cap_factor=cap_factor, threshold=threshold,
-                           skew_default=skew_default,
-                           shuffle_mode=shuffle_mode,
-                           use_kernel=use_kernel)
+                           cap_factor=cap_factor, use_kernel=use_kernel)
         out, metrics = sm(env, pvals) if has_params else sm(env)
         host = dict(SHUFFLE_STATS)
         runner = DistRunner(sm, host, pvals if has_params else None)
         metrics = _merge_host_stats({k: int(v) for k, v in metrics.items()},
                                     host)
-        if not adaptive or shuffle_mode != "packed" \
-                or attempt >= max_retries:
+        if not adaptive or attempt >= max_retries:
             break
         needs = {int(k.rsplit("_", 1)[1]): v for k, v in metrics.items()
                  if k.startswith("size_need_")}

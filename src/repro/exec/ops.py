@@ -22,8 +22,7 @@ raw key columns* (not by a packed hash), so a bag sorted by (G, A) is
 also grouped by every prefix — sum_by(G+A) feeding nest_level(G) costs
 one sort total, and a ``join -> sum_by -> nest_level`` pipeline sorts
 the probe side exactly once. ``SORT_STATS`` counts the sorts actually
-performed (the hook the fusion tests assert on); ``ORDER_AWARE`` is the
-global knob benchmarks flip to measure the unfused executor.
+performed (the hook the fusion tests assert on).
 
 Aggregation and join gathers can route through the Pallas kernels
 (interpret mode on CPU) or the jnp fallbacks.
@@ -31,7 +30,6 @@ Aggregation and join gathers can route through the Pallas kernels
 
 from __future__ import annotations
 
-from contextlib import contextmanager
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -46,10 +44,8 @@ I64_MAX = jnp.iinfo(jnp.int64).max
 
 
 # ---------------------------------------------------------------------------
-# physical-property plumbing: knob + sort accounting
+# physical-property plumbing: sort accounting
 # ---------------------------------------------------------------------------
-
-ORDER_AWARE = True   # False => recompute everything per operator (seed mode)
 
 from repro.obs.metrics import REGISTRY as _METRICS  # noqa: E402
 
@@ -65,18 +61,6 @@ def reset_sort_stats() -> None:
 
 def _count(name: str) -> None:
     _METRICS.inc("sort." + name)
-
-
-@contextmanager
-def order_awareness(enabled: bool):
-    """Scoped ORDER_AWARE toggle (benchmarks compare fused vs unfused)."""
-    global ORDER_AWARE
-    prev = ORDER_AWARE
-    ORDER_AWARE = enabled
-    try:
-        yield
-    finally:
-        ORDER_AWARE = prev
 
 
 def _cache_ok(bag: FlatBag, arr) -> bool:
@@ -98,13 +82,12 @@ def pack_keys(bag: FlatBag, cols: Sequence[str]) -> jnp.ndarray:
     rows are unspecified — consumers mask by validity."""
     cols = tuple(cols)
     assert cols, "empty key"
-    if ORDER_AWARE:
-        cached = bag.props.key_cache.get(cols)
-        if cached is not None:
-            _count("key_reuse")
-            return cached
+    cached = bag.props.key_cache.get(cols)
+    if cached is not None:
+        _count("key_reuse")
+        return cached
     key = combine64([bag.col(c) for c in cols])
-    if ORDER_AWARE and _cache_ok(bag, key):
+    if _cache_ok(bag, key):
         bag.props.key_cache[cols] = key
     return key
 
@@ -113,7 +96,7 @@ def _part_if(bag: FlatBag, cols) -> Optional[Tuple[str, ...]]:
     """The bag's hash-partitioning, propagated to an output whose
     columns ``cols`` keep their values: survives iff every partitioning
     column is among them (local ops never move rows across partitions)."""
-    part = bag.props.partitioning if ORDER_AWARE else None
+    part = bag.props.partitioning
     if part is not None and set(part) <= set(cols):
         return part
     return None
@@ -151,8 +134,7 @@ def _lexsort(bag: FlatBag, cols: Tuple[str, ...]) -> FlatBag:
                      for c, k in zip(cols, out[1:1 + len(keys)]))
         data = {n: moved[n] for n in bag.data}
     props = PhysicalProps(sorted_by=cols, invalid_last=True,
-                          partitioning=_part_if(bag, bag.data)) \
-        if ORDER_AWARE else None
+                          partitioning=_part_if(bag, bag.data))
     return FlatBag(data, valid, props)
 
 
@@ -197,7 +179,7 @@ def _segments(bag: FlatBag, key_cols: Sequence[str]
     the bag's ``sorted_by`` — the fusion that lets sum_by / dedup /
     nest_level chains on shared keys sort once."""
     cols = tuple(key_cols)
-    if ORDER_AWARE and bag.props.sorted_prefix(cols):
+    if bag.props.sorted_prefix(cols):
         sbag = bag
         cached = sbag.props.seg_cache.get(cols)
         if cached is not None:
@@ -209,7 +191,7 @@ def _segments(bag: FlatBag, key_cols: Sequence[str]
     with jax.named_scope("segment"):
         seg_id = _presorted_seg_ids(
             sbag, cols, sbag is not bag or bag.props.invalid_last)
-    if ORDER_AWARE and _cache_ok(sbag, seg_id):
+    if _cache_ok(sbag, seg_id):
         sbag.props.seg_cache[cols] = seg_id
     return sbag, seg_id
 
@@ -346,11 +328,9 @@ def sum_by(bag: FlatBag, key_cols: Sequence[str], val_cols: Sequence[str],
             sbag, seg_id, key_cols, use_kernel, val_cols)
     data = dict(firsts)
     data.update(summed)
-    props = None
-    if ORDER_AWARE:
-        props = PhysicalProps(sorted_by=key_cols,
-                              invalid_last=sbag.props.invalid_last,
-                              partitioning=_part_if(sbag, key_cols))
+    props = PhysicalProps(sorted_by=key_cols,
+                          invalid_last=sbag.props.invalid_last,
+                          partitioning=_part_if(sbag, key_cols))
     return FlatBag(data, out_valid, props)
 
 
@@ -362,12 +342,10 @@ def dedup(bag: FlatBag, cols: Optional[Sequence[str]] = None) -> FlatBag:
         prev = jnp.concatenate([jnp.full((1,), -1, seg_id.dtype),
                                 seg_id[:-1]])
         keep = (seg_id != prev) & sbag.valid
-    props = None
-    if ORDER_AWARE:
-        props = PhysicalProps(key_cache=dict(sbag.props.key_cache),
-                              sorted_by=sbag.props.sorted_by,
-                              invalid_last=False,
-                              partitioning=_part_if(sbag, sbag.data))
+    props = PhysicalProps(key_cache=dict(sbag.props.key_cache),
+                          sorted_by=sbag.props.sorted_by,
+                          invalid_last=False,
+                          partitioning=_part_if(sbag, sbag.data))
     return FlatBag(sbag.data, keep, props)
 
 
@@ -381,18 +359,17 @@ def _build_side(right: FlatBag, right_on: Tuple[str, ...]
     bag's props so repeated joins against one dictionary argsort once.
     A single-column build side already sorted on its key (e.g. a
     sum_by / dedup output) skips the argsort entirely."""
-    if ORDER_AWARE:
-        hit = right.props.build_cache.get(right_on)
-        if hit is not None:
-            _count("build_reuse")
-            return hit
+    hit = right.props.build_cache.get(right_on)
+    if hit is not None:
+        _count("build_reuse")
+        return hit
     rkey = pack_keys(right, right_on)
     rkey = jnp.where(right.valid, rkey, I64_MAX)
     # sorted_by order == packed-key order only for a single *integer*
     # key column (floats sort by bit pattern, hashes not at all)
     key_is_int = len(right_on) == 1 and jnp.issubdtype(
         right.col(right_on[0]).dtype, jnp.integer)
-    if ORDER_AWARE and key_is_int and right.props.invalid_last \
+    if key_is_int and right.props.invalid_last \
             and right.props.sorted_prefix(right_on):
         _count("build_sort_skipped")
         order_r = jnp.arange(right.capacity)
@@ -402,7 +379,7 @@ def _build_side(right: FlatBag, right_on: Tuple[str, ...]
         with jax.named_scope("sort"):
             order_r = jnp.argsort(rkey)
             srk = rkey[order_r]
-    if ORDER_AWARE and _cache_ok(right, srk):
+    if _cache_ok(right, srk):
         right.props.build_cache[right_on] = (order_r, srk)
     return order_r, srk
 
@@ -549,13 +526,11 @@ def fk_join(left: FlatBag, right: FlatBag, left_on: Sequence[str],
         if out_name in data:
             raise ValueError(f"join column collision: {out_name}")
         data[out_name] = jnp.where(matched, g, jnp.zeros_like(g))
-    props = None
-    if ORDER_AWARE:
-        lp = left.props
-        props = PhysicalProps(
-            key_cache=dict(lp.key_cache), sorted_by=lp.sorted_by,
-            invalid_last=lp.invalid_last if how == "left_outer" else False,
-            partitioning=_part_if(left, left.data))
+    lp = left.props
+    props = PhysicalProps(
+        key_cache=dict(lp.key_cache), sorted_by=lp.sorted_by,
+        invalid_last=lp.invalid_last if how == "left_outer" else False,
+        partitioning=_part_if(left, left.data))
     if how == "inner":
         return FlatBag(data, matched, props)
     assert how == "left_outer", how
@@ -635,11 +610,9 @@ def general_join(left: FlatBag, right: FlatBag, left_on: Sequence[str],
         # the paper's outer-unnest unique ID: one per output tuple
         data[rowid_col] = j.astype(jnp.int64)
     overflow = jnp.maximum(total - out_capacity, 0)
-    props = None
-    if ORDER_AWARE:
-        props = PhysicalProps(sorted_by=left.props.sorted_by,
-                              invalid_last=True,
-                              partitioning=_part_if(left, left.data))
+    props = PhysicalProps(sorted_by=left.props.sorted_by,
+                          invalid_last=True,
+                          partitioning=_part_if(left, left.data))
     return FlatBag(data, out_valid, props), overflow
 
 
@@ -689,11 +662,9 @@ def nest_level(bag: FlatBag, group_cols: Sequence[str],
 
     pdata = dict(firsts)
     pdata[label_col] = jnp.arange(cap, dtype=jnp.int64)
-    pprops = None
-    if ORDER_AWARE:
-        pprops = PhysicalProps(sorted_by=group_cols,
-                               invalid_last=sbag.props.invalid_last,
-                               partitioning=_part_if(sbag, group_cols))
+    pprops = PhysicalProps(sorted_by=group_cols,
+                           invalid_last=sbag.props.invalid_last,
+                           partitioning=_part_if(sbag, group_cols))
     parents = FlatBag(pdata, parent_valid, pprops)
 
     label = seg_id.astype(jnp.int64)
@@ -702,12 +673,10 @@ def nest_level(bag: FlatBag, group_cols: Sequence[str],
     child_valid = sbag.valid
     if child_valid_col is not None:
         child_valid = child_valid & sbag.col(child_valid_col)
-    cprops = None
-    if ORDER_AWARE:
-        cprops = PhysicalProps(key_cache={(label_col,): label},
-                               sorted_by=(label_col,),
-                               invalid_last=False,
-                               partitioning=_part_if(sbag, child_cols))
+    cprops = PhysicalProps(key_cache={(label_col,): label},
+                           sorted_by=(label_col,),
+                           invalid_last=False,
+                           partitioning=_part_if(sbag, child_cols))
     children = FlatBag(cdata, child_valid, cprops)
     return parents, children
 

@@ -281,7 +281,6 @@ class ExplainResult:
 def explain_analyze(program, env, input_types: Optional[dict] = None,
                     *, catalog=None, params: Optional[dict] = None,
                     skew_stats: Optional[dict] = None,
-                    skew_mode: str = "auto",
                     skew_partitions: int = 8,
                     hypercube_mode: str = "auto",
                     cost_mode: str = "off",
@@ -326,7 +325,6 @@ def explain_analyze(program, env, input_types: Optional[dict] = None,
     t0 = time.perf_counter()
     sp = M.shred_program(program, input_types, domain_elimination=True)
     cp = CG.compile_program(sp, catalog, skew_stats=skew_stats,
-                            skew_mode=skew_mode,
                             skew_partitions=skew_partitions,
                             hypercube_mode=hypercube_mode,
                             cost_mode=cost_mode,

@@ -205,7 +205,7 @@ def span(name: str, **attrs):
 
 @contextmanager
 def tracing(enabled: bool = True, reset: bool = False):
-    """Scoped tracer toggle (mirrors ``exec.ops.order_awareness``)."""
+    """Scoped tracer toggle."""
     prev = TRACER.enabled
     if reset:
         TRACER.reset()

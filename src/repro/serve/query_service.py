@@ -34,10 +34,9 @@ function runs under ``jax.vmap`` — one compiled computation serves the
 whole batch.
 
 **Automated skew handling** (DESIGN.md "Automated skew handling"):
-with ``skew_mode="auto"`` the compiler inserts ``SkewJoinP`` nodes
-wherever heavy-hitter statistics predict partition imbalance — from a
-stored dataset's persisted sketches (``execute_stored``), or from
-caller-supplied ``skew_hints`` ({bag: {column: heavy keys}}). The
+the compiler inserts ``SkewJoinP`` nodes wherever heavy-hitter
+statistics predict partition imbalance — from a stored dataset's
+persisted sketches (``execute_stored``), or from caller-supplied ``skew_hints`` ({bag: {column: heavy keys}}). The
 heavy-key sets ride as runtime parameters: the cache key carries only
 the hint *shape* ((bag, column) pairs), so a warm call with a
 DIFFERENT heavy-key set rebinds with zero retraces, exactly like
@@ -138,13 +137,11 @@ class QueryService:
                  domain_elimination: bool = True,
                  mesh=None, dist_kwargs: Optional[dict] = None,
                  max_entries: int = 64,
-                 skew_mode: str = "auto",
                  skew_threshold: float = 0.025,
                  skew_partitions: Optional[int] = None,
                  hypercube_mode: str = "auto",
                  feedback: Optional[object] = None,
                  cost_mode: str = "off"):
-        assert skew_mode in ("auto", "off"), skew_mode
         assert hypercube_mode in ("auto", "off"), hypercube_mode
         assert cost_mode in ("auto", "off"), cost_mode
         self.input_types = dict(input_types)
@@ -154,7 +151,6 @@ class QueryService:
         self.mesh = mesh
         self.dist_kwargs = dict(dist_kwargs or {})
         self.max_entries = max_entries
-        self.skew_mode = skew_mode
         self.hypercube_mode = hypercube_mode
         self.cost_mode = cost_mode
         self.skew_threshold = skew_threshold
@@ -239,7 +235,7 @@ class QueryService:
         at compile time), so the HyperCube share planner and the cost
         estimator can cost multiway chains over in-memory inputs that
         have no persisted sketches."""
-        if self.skew_mode == "off" or self.skew_partitions <= 1:
+        if self.skew_partitions <= 1:
             return None
         want_hc = self.mesh is not None and self.hypercube_mode == "auto"
         if not skew_hints and not want_hc and self.cost_mode != "auto":
@@ -354,7 +350,6 @@ class QueryService:
                              domain_elimination=self.domain_elim)
         cp = CG.compile_program(sp, self.catalog,
                                 skew_stats=skew_stats,
-                                skew_mode=self.skew_mode,
                                 skew_partitions=self.skew_partitions,
                                 skew_threshold=self.skew_threshold,
                                 hypercube_mode=self.hypercube_mode,
@@ -500,7 +495,7 @@ class QueryService:
         """Planner statistics for a stored dataset: the persisted
         streaming sketches + zone-map distinct counts, overridden by
         any caller hints (hinted keys count as definitely heavy)."""
-        if self.skew_mode == "off" or self.skew_partitions <= 1:
+        if self.skew_partitions <= 1:
             return None
         from repro.core.skew import TableStats
         from repro.storage import table_stats
@@ -538,7 +533,6 @@ class QueryService:
                     sp, self.catalog,
                     skew_stats=self._stored_skew_stats(dataset,
                                                        skew_hints),
-                    skew_mode=self.skew_mode,
                     skew_partitions=self.skew_partitions,
                     skew_threshold=self.skew_threshold,
                     hypercube_mode=self.hypercube_mode,
@@ -615,7 +609,6 @@ class QueryService:
             cp = CG.compile_program(
                 sp, self.catalog,
                 skew_stats=self._stored_skew_stats(dataset, skew_hints),
-                skew_mode=self.skew_mode,
                 skew_partitions=self.skew_partitions,
                 skew_threshold=self.skew_threshold,
                 hypercube_mode=self.hypercube_mode,

@@ -46,14 +46,34 @@ def test_distributed_shredded_route_matches_oracle():
         direct = I.eval_expr(running_example_query(), data)
         prog = N.Program([N.Assignment("Q", running_example_query())])
         sp = M.shred_program(prog, INPUT_TYPES, domain_elimination=True)
-        cp = CG.compile_program(sp, Catalog(unique_keys={"Part__F": ("pid",)}))
+        catalog = Catalog(unique_keys={"Part__F": ("pid",)})
         env = CG.columnar_shred_inputs(data, INPUT_TYPES)
         PN = 8
         env = {k: b.resize(((b.capacity + PN - 1)//PN)*PN)
                for k, b in env.items()}
         mesh = device_mesh_1d(PN)
+        # the planned skew path: the probe side's heavy keys come from a
+        # streaming sketch of its join column, as a stored dataset's
+        # footer would carry it
+        from repro.core import skew as SK
+        from repro.core.plans import SkewJoinP, _walk_plan
+        probe = env["COP__D_corders_oparts"]
+        pids = np.asarray(probe.col("pid"))[np.asarray(probe.valid)]
+        sketch = SK.HeavyKeySketch(k=64)
+        sketch.update(pids)
+        stats = {"COP__D_corders_oparts": SK.TableStats(
+            rows=len(pids), distinct={"pid": len(set(pids.tolist()))},
+            heavy={"pid": sketch.heavy(threshold=0.025)})}
+        plans = {False: CG.compile_program(sp, catalog),
+                 True: CG.compile_program(sp, catalog, skew_stats=stats,
+                                          skew_partitions=PN)}
+        (sj,) = [n for _, p in plans[True].plans for n in _walk_plan(p)
+                 if isinstance(n, SkewJoinP)]
+        assert [k for k in sj.heavy_default
+                if k != np.iinfo(np.int64).max] == [7], sj.heavy_default
         shuffles = {}
         for skew in (False, True):
+            cp = plans[skew]
             def fn(env_local, ctx):
                 out_env = CG.run_flat_program(cp, env_local,
                                               ExecSettings(dist=ctx))
@@ -61,7 +81,6 @@ def test_distributed_shredded_route_matches_oracle():
                 names = [man.top] + list(man.dicts.values())
                 return {k: out_env[k] for k in names}
             out, metrics = run_distributed(fn, env, mesh,
-                                           skew_default=skew,
                                            cap_factor=16.0)
             man = sp.manifests["Q"]
             parts = {(): out[man.top]}
@@ -70,7 +89,7 @@ def test_distributed_shredded_route_matches_oracle():
             result = CG.parts_to_rows(parts, running_example_query().ty)
             assert I.bags_equal(direct, result), f"skew={skew} mismatch"
             shuffles[skew] = metrics["shuffle_rows"]
-        # the skew-aware join must shuffle strictly less on zipf data
+        # the planned skew join must shuffle strictly less on zipf data
         assert shuffles[True] < shuffles[False], shuffles
         print("OK", shuffles)
     """)
@@ -213,7 +232,7 @@ def test_exchange_overflow_edge_and_adaptive():
 def test_exchange_elision_and_shuffle_stats():
     """Partitioning-aware elision: join -> sum_by on the same key moves
     probe rows across the wire exactly once; co-partitioned joins
-    exchange neither side; legacy mode does neither optimization."""
+    exchange neither side."""
     out = run_sub("""
         from repro.columnar.table import FlatBag
         L = FlatBag.from_rows([{"k": i % 7, "v": float(i)}
@@ -232,13 +251,12 @@ def test_exchange_elision_and_shuffle_stats():
             return {"out": ctx.sum_by(j, ("k",), ("v",),
                                       local_preagg=False)}
 
-        for mode, n_ex, n_el in (("packed", 2, 1), ("legacy", 3, 0)):
-            out, m = run_distributed(fn, {"L": L, "R": R}, mesh,
-                                     cap_factor=16.0, shuffle_mode=mode)
-            got = sorted((r["k"], r["v"]) for r in out["out"].to_rows())
-            assert got == sorted(want.items()), (mode, got)
-            assert m["exchanges"] == n_ex, (mode, m)
-            assert m["exchanges_elided"] == n_el, (mode, m)
+        out, m = run_distributed(fn, {"L": L, "R": R}, mesh,
+                                 cap_factor=16.0)
+        got = sorted((r["k"], r["v"]) for r in out["out"].to_rows())
+        assert got == sorted(want.items()), got
+        assert m["exchanges"] == 2, m
+        assert m["exchanges_elided"] == 1, m
         # a pre-partitioned probe flows through sum_by AND dedup with no
         # further exchange: one wire crossing for the whole pipeline
         def fn2(env, ctx):
@@ -303,16 +321,19 @@ def test_exchange_packed_kernel_path():
 
 @pytest.mark.slow
 def test_heavy_key_detection():
+    """``is_member`` against a planned heavy set padded by ``pad_heavy``:
+    present keys are members, absent keys and the padding value are
+    not, on the jnp path and the kernel path alike."""
     out = run_sub("""
         import jax.numpy as jnp
         from repro.core import skew as SK
-        key = jnp.concatenate([jnp.full((900,), 7, jnp.int64),
-                               jnp.arange(100, dtype=jnp.int64)])
-        valid = jnp.ones((1000,), bool)
-        hk = SK.heavy_keys_local(key, valid, sample=256, threshold=0.025)
-        member = SK.is_member(jnp.asarray([7, 3], jnp.int64),
-                              SK.merge_heavy(hk))
-        assert bool(member[0]) and not bool(member[1])
+        heavy = jnp.asarray(SK.pad_heavy([42, 7, -3]))
+        pad = int(np.iinfo(np.int64).max)
+        key = jnp.asarray([7, 3, 42, -3, 0, pad, 8, 41], jnp.int64)
+        want = [True, False, True, True, False, False, False, False]
+        for use_kernel in (False, True):
+            got = SK.is_member(key, heavy, use_kernel=use_kernel)
+            assert [bool(x) for x in got] == want, (use_kernel, got)
         print("OK")
     """)
     assert "OK" in out

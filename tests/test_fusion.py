@@ -3,8 +3,8 @@ plan ordering pass, and general_join overflow accounting.
 
 The headline acceptance: a ``join -> sum_by -> nest_level`` pipeline on
 shared keys sorts the probe side EXACTLY once (asserted through the
-SORT_STATS hook), and produces the same answer as the unfused executor
-(ORDER_AWARE=False recomputes everything per operator, seed-style)."""
+SORT_STATS hook), and produces the same answer as a plain-Python
+join -> group-sum -> nest over the input rows."""
 
 import numpy as np
 import pytest
@@ -42,35 +42,38 @@ def _pipeline(left, right, use_kernel=False):
                   for r in children.to_rows())
 
 
+def _reference(rows, n_right):
+    """``_pipeline`` in plain Python over the probe side's valid rows:
+    join on k with ``_mk_right(n_right)`` (keys 0..n_right-1, w = 10k),
+    sum v and w per (g, k), and nest the groups under g."""
+    sums = {}
+    for r in rows:
+        if r["k"] < n_right:
+            acc = sums.setdefault((r["g"], r["k"]), [0.0, 0.0])
+            acc[0] += r["v"]
+            acc[1] += float(r["k"] * 10)
+    return sorted((g, k, v, w) for (g, k), (v, w) in sums.items())
+
+
 # -- acceptance: one probe-side sort for join -> sum_by -> nest_level --------
 
 @pytest.mark.parametrize("use_kernel", [False, True])
 def test_pipeline_sorts_probe_side_exactly_once(use_kernel):
-    left, _ = _mk_left()
-    right = _mk_right()
-    fused = _pipeline(left, right, use_kernel=use_kernel)
+    left, rows = _mk_left()
+    fused = _pipeline(left, _mk_right(6), use_kernel=use_kernel)
     assert X.SORT_STATS.get("lexsort", 0) == 1, X.SORT_STATS
     assert X.SORT_STATS.get("sort_skipped", 0) >= 1, X.SORT_STATS
     # the one argsort is the (small) build side, never the probe side
     assert X.SORT_STATS.get("build_argsort", 0) <= 1, X.SORT_STATS
-
-    with X.order_awareness(False):
-        X.reset_sort_stats()
-        unfused = _pipeline(_mk_left()[0], _mk_right(),
-                            use_kernel=use_kernel)
-        assert X.SORT_STATS.get("lexsort", 0) == 2  # sum_by + nest_level
-    assert fused == unfused
+    assert fused == _reference(rows, 6)
 
 
 @settings(max_examples=20, deadline=None)
 @given(st.integers(1, 40), st.integers(1, 8), st.integers(0, 5))
-def test_fused_pipeline_matches_unfused(n, n_right, seed):
-    left, _ = _mk_left(n, seed)
-    right = _mk_right(n_right)
-    fused = _pipeline(left, right)
-    with X.order_awareness(False):
-        unfused = _pipeline(_mk_left(n, seed)[0], _mk_right(n_right))
-    assert fused == unfused
+def test_fused_pipeline_matches_reference(n, n_right, seed):
+    left, rows = _mk_left(n, seed)
+    fused = _pipeline(left, _mk_right(n_right))
+    assert fused == _reference(rows, n_right)
 
 
 # -- physical props propagation ----------------------------------------------
